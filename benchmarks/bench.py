@@ -181,9 +181,10 @@ def _sharded_child(argv) -> int:
 
     Scores one pre-materialized sharded cell with the cheap ``nextline2``
     prefetcher in this (fresh) interpreter and reports its own peak RSS
-    as JSON on stdout.  The JAX persistent-compilation-cache env vars are
-    inherited from the parent bench process, so a warmed cache makes the
-    child's peak free of compile-time transients.
+    as JSON on stdout.  The child places the JAX persistent compilation
+    cache by the same rule as the parent
+    (:func:`repro.core.exec.compile_cache.use_compile_cache`), so a warmed
+    cache makes the child's peak free of compile-time transients.
 
     The peak is read from ``/proc/self/status`` ``VmHWM``, which execve
     resets to this process's own image — ``getrusage``'s ``ru_maxrss``
@@ -275,8 +276,28 @@ def _grid_seconds(specs, pairs, cache_dir, workers, pipeline=True):
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else list(argv)
+    # One persistent JAX compilation cache shared by this process, every
+    # spawned worker and the gauge children: the untimed stage phase below
+    # warms it, so no timed measurement pays for XLA compiles.  The
+    # threshold must be set before the first jax import.
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    from repro.core.exec.compile_cache import use_compile_cache
+    from repro.core.exec.scheduler import jax_device
+
+    use_compile_cache()
     if argv and argv[0] == "--_score-sharded":
         return _sharded_child(argv[1:])
+    device, kind = jax_device()
+    if device != "cpu":
+        # Worker pools and the peak-RSS gauge children all import JAX, and
+        # a chip serves one process: nothing here can run as specified.
+        print(
+            f"[bench] refusing to run on {device} ({kind}): this harness "
+            "measures host-CPU worker pools and spawns JAX child processes, "
+            "but this process holds the chip. Run chip_smoke.py there.",
+            file=sys.stderr,
+        )
+        return 2
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
         "--smoke",
@@ -298,16 +319,8 @@ def main(argv=None) -> int:
     )
     args = ap.parse_args(argv)
 
-    # One persistent JAX compilation cache shared by this process and every
-    # spawned worker (the scheduler exports a pre-set dir to its children):
-    # the untimed stage phase below warms it, so no timed measurement pays
-    # for XLA compiles.  Must be set before the first jax import.
     cache_dir = args.cache_dir or tempfile.mkdtemp(prefix="repro-bench-cache-")
     own_cache_dir = args.cache_dir is None
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR", os.path.join(cache_dir, "jax-cache")
-    )
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
     from repro.core import WorkloadSpec
     from repro.core.exec.scheduler import rows_equal

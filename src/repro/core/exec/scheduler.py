@@ -43,6 +43,8 @@ import numpy as np
 import repro
 from repro.core.driver import WorkloadSpec, WorkloadTrace
 from repro.core.exec.artifacts import ArtifactCache
+from repro.core.exec.compile_cache import ENV_VAR as JAX_CACHE_ENV
+from repro.core.exec.compile_cache import use_compile_cache
 from repro.core.exec.timers import record
 from repro.core.experiment import score_prefetcher
 from repro.core.obs import spans as obs
@@ -448,6 +450,54 @@ def _available_mem_bytes() -> Optional[int]:
     return None
 
 
+def jax_device() -> Tuple[str, str]:
+    """Platform and kind of the device this process drives.
+
+    The probe the one-process rule reads: on any backend but ``cpu`` the
+    process holds its chip, and a spawned child that imports JAX would
+    fail or hang on it.
+    """
+    import jax
+
+    device = jax.devices()[0]
+    return device.platform, device.device_kind
+
+
+def device_decision() -> Optional[SchedDecision]:
+    """The serial decision forced by an accelerator backend, else None."""
+    platform, kind = jax_device()
+    if platform == "cpu":
+        return None
+    return SchedDecision(
+        mode="serial",
+        workers=1,
+        est_serial_s=0.0,
+        est_pool_s=None,
+        reason=(
+            f"JAX runs on {platform} ({kind}): this process holds the chip, "
+            "so no pool of JAX processes"
+        ),
+        cores=os.cpu_count() or 1,
+        n_tasks=0,
+        measured_frac=0.0,
+    )
+
+
+def require_host_backend(workers: int) -> None:
+    """Refuse a pool of ``workers`` JAX processes off the CPU backend.
+
+    Children are never pinned to the CPU to get around this: that would
+    move scoring off the chip without anyone seeing it.
+    """
+    platform, kind = jax_device()
+    if workers > 1 and platform != "cpu":
+        raise RuntimeError(
+            f"workers={workers} spawns JAX processes, but this process holds "
+            f"the {platform} device ({kind}) and a chip serves one process; "
+            "run with workers=1 or workers=None"
+        )
+
+
 def plan_execution(
     specs: Sequence,
     n_prefetchers: int,
@@ -462,6 +512,9 @@ def plan_execution(
     deterministic tests).  This is what ``Experiment.run(workers=None)``
     consults instead of the old blind ``min(cores, builds)``.
     """
+    forced = device_decision()
+    if forced is not None:
+        return forced
     artifacts = artifacts if artifacts is not None else ArtifactCache()
     if cores is None:
         cores = os.cpu_count() or 1
@@ -498,20 +551,19 @@ def rows_equal(a: List[dict], b: List[dict]) -> bool:
 
 
 @contextlib.contextmanager
-def _spawn_pool(
-    artifacts: ArtifactCache, n_tasks: int, workers: int
-) -> Iterator[ProcessPoolExecutor]:
+def _spawn_pool(n_tasks: int, workers: int) -> Iterator[ProcessPoolExecutor]:
     """A spawned process pool with the engine's worker environment.
 
     Spawned interpreters re-import the package from scratch, so the parent
-    exports: the ``repro`` source root on ``PYTHONPATH``; a persistent JAX
-    compilation cache next to the workload artifacts (re-JITting the
-    lax.scan cache passes costs seconds per process otherwise — an
-    externally-set cache dir wins so a parent that set one shares its
-    compiles); and the current cache-engine selection, which may live in
+    exports: the ``repro`` source root on ``PYTHONPATH``; the parent's
+    persistent JAX compilation cache (:mod:`repro.core.exec.compile_cache`
+    — re-JITting the lax.scan cache passes costs seconds per process
+    otherwise); and the current cache-engine selection, which may live in
     process-local state the children would never see.  The environment is
-    restored when the pool closes.
+    restored when the pool closes.  Off the CPU backend no pool opens at
+    all (:func:`require_host_backend`).
     """
+    require_host_backend(workers)
     # repro may be a namespace package (no __init__), so resolve its
     # directory via __path__ when __file__ is absent.
     if getattr(repro, "__file__", None):
@@ -521,9 +573,7 @@ def _spawn_pool(
     src_root = os.path.dirname(pkg_dir)
     old_pythonpath = os.environ.get("PYTHONPATH")
     pythonpath = [src_root] + ([old_pythonpath] if old_pythonpath else [])
-    jax_cache = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR", str(artifacts.root / "jax-cache")
-    )
+    jax_cache = use_compile_cache()
     from repro.apps.trace import EMITTER_ENV, current_emitter
     from repro.memsim.engine import ENGINE_ENV, current_engine
 
@@ -548,7 +598,7 @@ def _spawn_pool(
     )
     child_env = {
         "PYTHONPATH": os.pathsep.join(pythonpath),
-        "JAX_COMPILATION_CACHE_DIR": jax_cache,
+        JAX_CACHE_ENV: jax_cache,
         # Cache even sub-second compiles (the default threshold is 1s).
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": os.environ.get(
             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0"
@@ -638,7 +688,7 @@ def run_grid(
 
     traces: Dict[WorkloadSpec, WorkloadTrace] = {}
     metrics: Dict[tuple, PrefetchMetrics] = {}
-    with _spawn_pool(artifacts, len(tasks), workers) as pool:
+    with _spawn_pool(len(tasks), workers) as pool:
         futures = {
             pool.submit(_run_task, (i, spec, chunk, str(artifacts.root))): i
             for i, (spec, chunk) in enumerate(tasks)
@@ -706,7 +756,7 @@ def _run_grid_pipelined(
         len(whole) + (len(warm) + len(cold)) * chunks_per + len(cold)
     )
     overlap = 0.0
-    with _spawn_pool(artifacts, n_tasks_est, workers) as pool:
+    with _spawn_pool(n_tasks_est, workers) as pool:
         score_futs: set = set()
         build_futs: Dict[object, WorkloadSpec] = {}
 
@@ -811,7 +861,7 @@ class MaterializePipeline:
         if todo:
             self._stack = contextlib.ExitStack()
             pool = self._stack.enter_context(
-                _spawn_pool(artifacts, len(todo), workers)
+                _spawn_pool(len(todo), workers)
             )
             # FIFO submission: the scorer consumes epochs in sequence
             # order, so the build it will wait on first starts first.

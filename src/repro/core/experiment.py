@@ -524,7 +524,13 @@ class Experiment:
         in-process.  On a single core, under memory pressure, or with
         unpicklable ad-hoc prefetchers (which cannot cross the spawn
         boundary) the run degrades to serial with no pool at all.  The
-        decision is surfaced as ``result.sched``.
+        decision is surfaced as ``result.sched``.  On any JAX backend but
+        ``cpu`` this process holds the chip: ``workers=None`` resolves
+        serial and ``workers>1`` raises, since spawned children could not
+        reach the device.
+
+        The persistent JAX compilation cache is placed by
+        :func:`repro.core.exec.compile_cache.use_compile_cache`.
 
         ``pipeline`` selects the overlapped schedule (score tasks
         dispatched as their builds complete) over the legacy phased
@@ -558,6 +564,9 @@ class Experiment:
     def _run_impl(
         self, verbose: bool, workers: Optional[int], pipeline: bool
     ) -> ExperimentResult:
+        from repro.core.exec.compile_cache import use_compile_cache
+
+        use_compile_cache()
         sched = None
         if workers is None:
             sched = self._plan_schedule()
@@ -666,14 +675,18 @@ class Experiment:
         epochs, serve tenants — is costed against the artifact store;
         :func:`repro.core.exec.scheduler.plan_execution` then picks
         serial in-process execution or a pipelined pool sized from the
-        predicted makespan.  Unpicklable ad-hoc prefetchers force serial
-        (``workers=N`` rejects them loudly, but a *default* must
-        tolerate them)."""
+        predicted makespan.  A JAX backend other than ``cpu`` forces
+        serial (this process holds the chip), and so do unpicklable ad-hoc
+        prefetchers (``workers=N`` rejects them loudly, but a *default*
+        must tolerate them)."""
         import os
         import pickle
 
         from repro.core.exec import scheduler  # lazy: avoids import cycle
 
+        forced = scheduler.device_decision()
+        if forced is not None:
+            return forced
         try:
             for _, gen in self.prefetchers:
                 pickle.dumps(gen)

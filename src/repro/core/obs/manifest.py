@@ -44,8 +44,11 @@ def git_sha() -> Optional[str]:
 
 def run_manifest(sched: Optional[dict] = None, **extra) -> dict:
     """Provenance snapshot: git sha, resolved engine/emitter, schema
-    versions, interpreter/platform, and (when the caller has one) the
-    scheduler's ``SchedDecision`` record plus free-form extras."""
+    versions, interpreter/platform, the JAX device the run drove, and
+    (when the caller has one) the scheduler's ``SchedDecision`` record
+    plus free-form extras."""
+    import jax
+
     from repro.apps.trace import current_emitter
     from repro.core.driver import TRACE_CODE_VERSION
     from repro.core.exec.artifacts import ARTIFACT_SCHEMA
@@ -61,6 +64,11 @@ def run_manifest(sched: Optional[dict] = None, **extra) -> dict:
         "trace_schema": TRACE_SCHEMA,
         "python": sys.version.split()[0],
         "platform": platform.platform(),
+        "device": {
+            "platform": jax.devices()[0].platform,
+            "kind": jax.devices()[0].device_kind,
+            "count": len(jax.devices()),
+        },
         "pid": os.getpid(),
     }
     if sched is not None:

@@ -4,19 +4,23 @@ Reuses the engine's stable group-by-set partitioning so the kernel, the
 batched-scan engine, and the serial reference all consume identical padded
 substreams — the kernel only changes *where* the per-set machines run.
 
-Backend gating: on TPU the kernel compiles natively; off-TPU it falls back
-to interpret mode, which validates semantics (tests) but is not a fast
-path — the default ``set_parallel`` engine is the CPU production path.
+Backend gating: on TPU the kernel compiles natively; on CPU it runs in
+interpret mode, which validates semantics (tests) but is not a fast path.
+Any other backend raises (:func:`interpret_mode`).
 """
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.cache_sim.cache_sim import lru_hits, lru_hits_carry
+from repro.kernels.cache_sim.cache_sim import (
+    LANES,
+    interpret_mode,
+    lru_hits,
+    lru_hits_carry,
+)
 from repro.kernels.cache_sim.ref import lru_hits_ref
 
 __all__ = ["cache_pass_pallas", "lru_hits", "lru_hits_carry", "lru_hits_ref"]
@@ -46,29 +50,18 @@ def cache_pass_pallas(
         st = state if state is not None else engine.init_state(sets, ways)
         return hits, engine.CacheState(st.tags.copy(), st.age.copy())
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if set_tile is None:
-        set_tile = min(sets, 8)
+        interpret = interpret_mode()
     padded, order, col, row = engine.group_by_set(blocks, sets)
-    mat = np.ascontiguousarray(padded.T)  # (sets, L): sets->sublanes
-    if state is None and not return_state:
-        hits = np.asarray(
-            lru_hits(
-                jnp.asarray(mat), ways, set_tile=set_tile, interpret=interpret
-            )
-        )
-    else:
-        st = state if state is not None else engine.init_state(sets, ways)
-        hits, tags1, age1 = lru_hits_carry(
-            jnp.asarray(mat),
-            jnp.asarray(st.tags),
-            jnp.asarray(st.age),
-            set_tile=set_tile,
-            interpret=interpret,
-        )
-        hits = np.asarray(hits)
+    st = state if state is not None else engine.init_state(sets, ways)
+    hits, tags1, age1 = lru_hits_carry(
+        jnp.asarray(padded),
+        jnp.asarray(st.tags),
+        jnp.asarray(st.age),
+        set_tile=set_tile or LANES,
+        interpret=interpret,
+    )
     out = np.zeros(len(blocks), dtype=bool)
-    out[order] = hits[row, col].astype(bool)
+    out[order] = np.asarray(hits)[col, row].astype(bool)
     if not return_state:
         return out
     return out, engine.canonicalize_state(np.asarray(tags1), np.asarray(age1))

@@ -40,13 +40,14 @@ Engines (pick with ``REPRO_CACHE_ENGINE``, :func:`set_engine`, or the
   property tests and the bench parity gate compare against — including
   across shard seams (see *carried state* below).
 - ``pallas``: the same set-parallel machine as a Pallas TPU kernel
-  (:mod:`repro.kernels.cache_sim`), sets tiled across the grid with the
-  tag/age carry in VMEM scratch.  Off-TPU it runs in interpret mode,
-  which validates semantics but is not fast.
+  (:mod:`repro.kernels.cache_sim`): sets on lanes, time streamed through
+  VMEM in chunks, the tag/age carry in VMEM scratch.  It compiles on TPU
+  and runs in interpret mode on CPU (tests only: correct, not fast); any
+  other backend raises.
 
 The default engine is resolved per backend: ``pallas`` on TPU (the kernel
-is the native scoring path on accelerator), ``set_parallel`` everywhere
-else.  ``REPRO_CACHE_ENGINE`` overrides the resolution either way.
+is the native scoring path on the chip), ``fused`` everywhere else.
+``REPRO_CACHE_ENGINE`` overrides the resolution either way.
 
 **Carried state.**  Sharded traces stream through the simulator one chunk
 at a time, so every engine can resume a pass exactly where the previous
@@ -75,7 +76,7 @@ from repro.memsim import scan_cache
 
 ENGINES = ("fused", "set_parallel", "reference", "pallas")
 ENGINE_ENV = "REPRO_CACHE_ENGINE"
-# CPU/GPU default; see default_engine() for the backend-aware resolution.
+# Default off TPU; see default_engine() for the backend-aware resolution.
 DEFAULT_ENGINE = "fused"
 
 _override: Optional[str] = None
@@ -90,13 +91,9 @@ def _check(name: str) -> str:
 @lru_cache(maxsize=1)
 def default_engine() -> str:
     """Backend-resolved default: the Pallas kernel on TPU, the fused
-    hierarchy engine elsewhere (where the Pallas kernel would run in slow
-    interpret mode)."""
-    try:
-        backend = jax.default_backend()
-    except Exception:  # backend discovery failed -> portable default
-        backend = "cpu"
-    return "pallas" if backend == "tpu" else DEFAULT_ENGINE
+    hierarchy engine elsewhere.  A backend that fails to initialize is an
+    error, not a reason to carry on as CPU."""
+    return "pallas" if jax.default_backend() == "tpu" else DEFAULT_ENGINE
 
 
 def current_engine() -> str:

@@ -448,15 +448,16 @@ def fused_cache_pass(
         # take the bit-identical per-level cascade instead.
         return _fused_fallback(blocks, levels, sts, return_states)
     if use_pallas:
+        from repro.kernels.cache_sim.cache_sim import interpret_mode
         from repro.kernels.cache_sim.fused_sim import fused_levels_pallas
 
         res = fused_levels_pallas(
-            jnp.asarray(padded.T),
+            jnp.asarray(padded),
             levels,
             *_grouped_state_args(sts, groups),
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret_mode(),
         )
-        lvls = np.asarray(res[0]).T
+        lvls = np.asarray(res[0])
     else:
         res = _fused_scan(levels)(
             jnp.asarray(padded), *_pack_state_args(sts, levels)

@@ -5,6 +5,9 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# Tests write no persistent compilation cache into the checkout; spawned
+# pool workers inherit the setting through the environment.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 import numpy as np
 import pytest

@@ -178,12 +178,52 @@ def test_cache_sim_kernel_vs_oracle(sets, ways, n, span, seed):
 
     rng = np.random.default_rng(seed)
     blocks = rng.integers(0, span, n).astype(np.int64)
-    padded, _, _, _ = group_by_set(blocks, sets)
-    mat = np.ascontiguousarray(padded.T)  # (sets, L)
-    got = np.asarray(lru_hits(jnp.asarray(mat), ways, set_tile=sets, interpret=True))
-    ref = lru_hits_ref(mat, ways)
-    real = mat >= 0  # oracle skips tail pads; the kernel runs over them
+    padded, _, _, _ = group_by_set(blocks, sets)  # (L, sets)
+    got = np.asarray(lru_hits(jnp.asarray(padded), ways, set_tile=sets, interpret=True))
+    ref = lru_hits_ref(np.ascontiguousarray(padded.T), ways).T
+    real = padded >= 0  # oracle skips tail pads; the kernel runs over them
     np.testing.assert_array_equal(got[real], ref[real])
+
+
+@pytest.mark.parametrize("kernel", ["lru", "fused"])
+def test_cache_kernels_carry_across_time_chunks(kernel):
+    """A resumed pass whose substreams span several VMEM time chunks is
+    bit-identical to one uninterrupted reference pass: hit results and the
+    canonical carried states (ages keep increasing across chunks)."""
+    from repro.kernels.cache_sim.cache_sim import TIME_CHUNK
+    from repro.memsim.engine import group_by_set
+    from repro.memsim.fused import fused_cache_pass
+    from repro.memsim.scan_cache import cache_pass as cache_pass_reference
+
+    rng = np.random.default_rng(11)
+    blocks = rng.integers(0, 300, 12_000).astype(np.int64)
+    h = 5_000
+    sets, ways = 4, 2
+    assert group_by_set(blocks[h:], sets)[0].shape[0] > TIME_CHUNK
+    if kernel == "lru":
+        ref, ref_st = cache_pass_reference(blocks, sets, ways, None, True)
+        first, st = cache_pass_pallas(
+            blocks[:h], sets, ways, interpret=True, return_state=True
+        )
+        second, st = cache_pass_pallas(
+            blocks[h:], sets, ways, interpret=True, state=st, return_state=True
+        )
+        ref_sts, got_sts = [ref_st], [st]
+    else:
+        geom = ((sets, ways), (2 * sets, ways), (8 * sets, 2 * ways))
+        ref, ref_sts = fused_cache_pass(
+            blocks, geom, return_states=True, use_pallas=False, force_scan=True
+        )
+        first, sts = fused_cache_pass(
+            blocks[:h], geom, return_states=True, use_pallas=True
+        )
+        second, got_sts = fused_cache_pass(
+            blocks[h:], geom, states=sts, return_states=True, use_pallas=True
+        )
+    np.testing.assert_array_equal(np.concatenate([first, second]), ref)
+    for got, want in zip(got_sts, ref_sts):
+        np.testing.assert_array_equal(got.tags, want.tags)
+        np.testing.assert_array_equal(got.age, want.age)
 
 
 def test_cache_sim_full_stream_matches_reference_engine():

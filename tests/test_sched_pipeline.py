@@ -167,6 +167,70 @@ def test_run_on_single_core_resolves_serial(monkeypatch, tmp_path):
     assert forced.sched is None
 
 
+def _on_chip(monkeypatch):
+    """Make the scheduler's backend probe report a TPU (JAX itself stays
+    on the CPU, so the run still executes here)."""
+    monkeypatch.setattr(scheduler, "jax_device", lambda: ("tpu", "TPU v5 lite"))
+
+
+def test_accelerator_backend_resolves_serial(monkeypatch, tmp_path):
+    """One process holds the chip: ``workers=None`` never opens a pool
+    there, whatever the cost model would say, and the reason names the
+    device."""
+    _on_chip(monkeypatch)
+    arts = ArtifactCache(tmp_path)
+    specs = [
+        WorkloadSpec(kernel="pgd", dataset="road-ca"),
+        WorkloadSpec(kernel="pgd", dataset="google"),
+    ]
+    d = scheduler.plan_execution(specs, 2, arts, cores=64, mem_bytes=1 << 40)
+    assert d.mode == "serial" and d.workers == 1
+    assert "tpu" in d.reason and "TPU v5 lite" in d.reason
+    result = Experiment(
+        workloads=[WorkloadSpec(kernel="pgd", dataset=TINY)],
+        prefetchers=["nextline2"],
+        cache=WorkloadCache(artifacts=arts),
+    ).run()
+    assert result.sched["mode"] == "serial" and result.sched["workers"] == 1
+    assert "TPU v5 lite" in result.sched["reason"]
+    assert isinstance(result.workloads, dict)  # no pool path ran
+
+
+def test_accelerator_backend_refuses_worker_pool(monkeypatch, tmp_path):
+    _on_chip(monkeypatch)
+    exp = Experiment(
+        workloads=[WorkloadSpec(kernel="pgd", dataset=TINY)],
+        prefetchers=["nextline2"],
+        cache=WorkloadCache(artifacts=ArtifactCache(tmp_path)),
+    )
+    with pytest.raises(RuntimeError, match="workers=2 spawns JAX processes"):
+        exp.run(workers=2)
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """An external ``JAX_COMPILATION_CACHE_DIR`` is left to JAX alone;
+    without one the cache is a fixed directory inside the checkout."""
+    import pathlib
+
+    import jax
+
+    from repro.core.exec import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        assert compile_cache.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        checkout = pathlib.Path(__file__).resolve().parents[1]
+        path = compile_cache.use_compile_cache()
+        assert path == str(checkout / ".jax_cache")
+        assert compile_cache.use_compile_cache() == path  # fixed across calls
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 # ------------------------------------------------- delta-aware trace reuse
 
 
