@@ -1,0 +1,139 @@
+"""Decide ``correct``: the timed path's answers against the plain reference.
+
+Three numbers, each an exact comparison with the limit 0:
+
+- ``trace_mismatch``: accesses whose line, or iteration, differs between
+  the simulator's emitted trace and the reference's, plus the difference
+  in length and in the first scored position;
+- ``hit_mismatch``: L1, L2 and LLC demand hit bits that differ;
+- ``row_gap``: the largest relative gap over every field of every scored
+  row (speedup, coverage, accuracy, counts and traffic).
+
+The reference takes the configuration and the job's seed, and for each row
+the prefetch stream that the evaluated prefetcher issued on the timed path.
+"""
+from __future__ import annotations
+
+import importlib
+import math
+
+import numpy as np
+
+from reference import graphs, scoring
+
+LIMITS = {"trace_mismatch": 0, "hit_mismatch": 0, "row_gap": 0}
+ROW_FIELDS = (
+    "speedup",
+    "coverage",
+    "accuracy",
+    "ipc_baseline",
+    "ipc_prefetch",
+    "issued",
+    "useful",
+    "late",
+    "evicted_early",
+    "overpredicted",
+    "redundant",
+    "baseline_l2_misses",
+    "extra_traffic",
+    "metadata_traffic",
+    "dram_demand",
+    "dram_total",
+)
+
+
+def _differ(a: np.ndarray, b: np.ndarray) -> int:
+    n = min(len(a), len(b))
+    return int(np.count_nonzero(np.asarray(a[:n]) != np.asarray(b[:n]))) + abs(
+        len(a) - len(b)
+    )
+
+
+def _gap(got: float, want: float) -> float:
+    if got == want:
+        return 0.0
+    if not (math.isfinite(got) and math.isfinite(want)):
+        return math.inf
+    return abs(got - want) / max(abs(want), 1e-12)
+
+
+class Reference:
+    """The configuration's plain reference, with its base graph made once."""
+
+    def __init__(self, config: dict, policy: str = "lru"):
+        self.config = config
+        self.policy = policy
+        self.workload = importlib.import_module(f"reference.{config['reference']}")
+        self._graph = None
+        self._last = None  # (seed, trace, demand) of the last workload
+
+    @property
+    def graph(self) -> graphs.Graph:
+        if self._graph is None:
+            self._graph = graphs.make_graph(self.config["graph"])
+        return self._graph
+
+    def simulate(self, seed: int) -> tuple:
+        """The workload's trace and its demand masks (the last one kept,
+        since a job sweep scores one workload many times)."""
+        if self._last is None or self._last[0] != seed:
+            trace = self.workload.trace(self.config, seed, self.graph)
+            d = scoring.demand(trace["blocks"], self.config["hierarchy"], self.policy)
+            self._last = (seed, trace, d)
+        return self._last[1], self._last[2]
+
+    def row(self, d: scoring.Demand, stream: tuple, eval_from: int) -> dict:
+        h, tm = self.config["hierarchy"], self.config["timing"]
+        return scoring.score(d, stream, eval_from, h, tm)
+
+
+def compare(jobs: list, ref: Reference, truth: Reference | None = None) -> dict:
+    """The three numbers over ``jobs``.
+
+    Each job is a dict with ``workloads`` (per workload: ``seed``,
+    ``block``, ``iter_id``, ``eval_from``, ``l1_hit``, ``l2_hit``,
+    ``llc_hit`` and ``rows``, a list of ``(row, stream)``).  With ``truth``
+    given, ``ref`` stands in for the timed path (the control): its answers
+    replace the program's and ``truth`` judges them.
+    """
+    out = {"trace_mismatch": 0, "hit_mismatch": 0, "row_gap": 0.0}
+    for job in jobs:
+        for w in job["workloads"]:
+            want, d = (truth or ref).simulate(w["seed"])
+            streams = [stream for _, stream in w["rows"]]
+            if truth is None:
+                got = dict(w, rows=[row for row, _ in w["rows"]])
+            else:
+                got = _answers(ref, w["seed"], streams)
+            out["trace_mismatch"] += (
+                _differ(got["block"], want["blocks"])
+                + _differ(got["iter_id"], want["iter_id"])
+                + abs(int(got["eval_from"]) - int(want["eval_from"]))
+            )
+            for level in ("l1_hit", "l2_hit", "llc_hit"):
+                out["hit_mismatch"] += _differ(got[level], getattr(d, level))
+            for row, stream in zip(got["rows"], streams):
+                expect = (truth or ref).row(d, stream, int(want["eval_from"]))
+                for field in ROW_FIELDS:
+                    out["row_gap"] = max(
+                        out["row_gap"], _gap(float(row[field]), float(expect[field]))
+                    )
+    return out
+
+
+def _answers(ref: Reference, seed: int, streams: list) -> dict:
+    """What ``ref`` itself answers for one workload, shaped like a job's."""
+    trace, d = ref.simulate(seed)
+    return dict(
+        block=trace["blocks"],
+        iter_id=trace["iter_id"],
+        eval_from=trace["eval_from"],
+        l1_hit=d.l1_hit,
+        l2_hit=d.l2_hit,
+        llc_hit=d.llc_hit,
+        rows=[ref.row(d, stream, trace["eval_from"]) for stream in streams],
+    )
+
+
+def verdict(numbers: dict) -> bool:
+    return all(numbers[k] <= limit for k, limit in LIMITS.items())
