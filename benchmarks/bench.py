@@ -51,8 +51,7 @@ Schema v8 adds the telemetry section (``docs/OBSERVABILITY.md``): the
 scheduler's auto warm run executes under a cross-process span tracer, and
 the committed document carries the run manifest (git sha, resolved
 engine/emitter, schema versions, SchedDecision), the merged metrics
-registry snapshot (cache hit/build counters, per-stage latency
-histograms), and the merged span-trace summary covering parent and
+registry snapshot (cache hit/build counters), and the merged span-trace summary covering parent and
 worker processes.  ``tools/bench_diff.py`` gates CI on consecutive
 documents; ``tools/trace_export.py`` renders traces for Perfetto.
 

@@ -19,9 +19,8 @@ Two layers:
 
 ``stage``/``collect_stages``/``record`` are now thin re-exports of
 :mod:`repro.core.obs.spans`: the same stage names double as structured
-spans (and per-stage latency histograms) when a tracer or metrics
-registry is active, with the flat stage-dict semantics — including the
-no-op fast path — unchanged.  See docs/OBSERVABILITY.md.
+spans when a tracer is active, with the flat stage-dict semantics —
+including the no-op fast path — unchanged.  See docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations

@@ -62,7 +62,33 @@ def score_prefetcher(
         kernel=workload.spec.kernel,
         dataset=workload.spec.dataset,
     ), stage("score"):
-        stream = generate(workload)
+        with obs.span(f"score.generate[{name}]"):
+            stream = generate(workload)
+        blocks, pos, issuer = _composite_stream(workload, stream)
+        outcome = simulate_with_prefetch(
+            workload.profile,
+            blocks,
+            pos,
+            pf_issuer=issuer,
+            metadata_bytes=stream.metadata_bytes,
+        )
+        with obs.span("score.evaluate"):
+            m = evaluate(
+                name,
+                workload.profile,
+                outcome,
+                baseline_outcome=workload.nl_outcome,
+                eval_from_pos=workload.eval_from_pos,
+                issuer=1,
+            )
+        m.info = stream.info  # attach prefetcher-side stats
+    return m
+
+
+def _composite_stream(workload: WorkloadTrace, stream):
+    """The next-line stream (issuer 0) followed by ``stream`` (issuer 1):
+    blocks, positions and issuer ids of the composite configuration."""
+    with obs.span("prefetch.merge"):
         blocks = np.concatenate([workload.nl_blocks, stream.blocks])
         pos = np.concatenate([workload.nl_pos, stream.pos])
         issuer = np.concatenate(
@@ -71,23 +97,7 @@ def score_prefetcher(
                 np.ones(len(stream.blocks), np.int8),
             ]
         )
-        outcome = simulate_with_prefetch(
-            workload.profile,
-            blocks,
-            pos,
-            pf_issuer=issuer,
-            metadata_bytes=stream.metadata_bytes,
-        )
-        m = evaluate(
-            name,
-            workload.profile,
-            outcome,
-            baseline_outcome=workload.nl_outcome,
-            eval_from_pos=workload.eval_from_pos,
-            issuer=1,
-        )
-        m.info = stream.info  # attach prefetcher-side stats
-    return m
+    return blocks, pos, issuer
 
 
 def score_prefetchers_batched(
@@ -121,30 +131,23 @@ def score_prefetchers_batched(
                 kernel=workload.spec.kernel,
                 dataset=workload.spec.dataset,
                 batched=True,
-            ):
+            ), obs.span(f"score.generate[{name}]"):
                 stream = gen(workload)
-            blocks = np.concatenate([workload.nl_blocks, stream.blocks])
-            pos = np.concatenate([workload.nl_pos, stream.pos])
-            issuer = np.concatenate(
-                [
-                    np.zeros(len(workload.nl_blocks), np.int8),
-                    np.ones(len(stream.blocks), np.int8),
-                ]
-            )
-            items.append((blocks, pos, issuer))
+            items.append(_composite_stream(workload, stream))
             metas.append(stream.metadata_bytes)
             infos.append(stream.info)
         outcomes = simulate_with_prefetch_batch(workload.profile, items, metas)
         out = []
         for (name, _), outcome, info in zip(pairs, outcomes, infos):
-            m = evaluate(
-                name,
-                workload.profile,
-                outcome,
-                baseline_outcome=workload.nl_outcome,
-                eval_from_pos=workload.eval_from_pos,
-                issuer=1,
-            )
+            with obs.span("score.evaluate"):
+                m = evaluate(
+                    name,
+                    workload.profile,
+                    outcome,
+                    baseline_outcome=workload.nl_outcome,
+                    eval_from_pos=workload.eval_from_pos,
+                    issuer=1,
+                )
             m.info = info
             out.append(m)
     return out

@@ -19,11 +19,15 @@ paths pay nothing when telemetry is off:
 - **Tracer** (``trace``): records :class:`Span` objects.  :func:`stage`
   doubles as a span when a tracer is active, so every existing stage
   site shows up on the timeline for free; :func:`span` is the
-  attribute-bearing form for new instrumentation.
+  attribute-bearing form for new instrumentation.  While a tracer is
+  active each span also enters a ``jax.profiler.TraceAnnotation`` of
+  its name, so a running profiler trace shows it on its ``/host:`` plane
+  beside the device ops, and JAX's compiles are recorded as
+  ``jax_compile`` spans (:func:`_on_compile_end`).
 - **Metrics registry**: the active tracer owns a
-  :class:`~repro.core.obs.metrics.MetricsRegistry`; :func:`stage` feeds
-  per-stage latency histograms, and the :func:`inc` / :func:`observe` /
-  :func:`set_gauge` helpers feed counters and gauges from anywhere.
+  :class:`~repro.core.obs.metrics.MetricsRegistry`; the :func:`inc` /
+  :func:`observe` / :func:`set_gauge` helpers feed counters, histograms
+  and gauges from anywhere.
 
 Cross-process collection: a :class:`Tracer` opened with a directory
 exports nothing itself — the pool spawner
@@ -44,10 +48,14 @@ import contextlib
 import dataclasses
 import json
 import os
+import threading
 import time
 import uuid
 from pathlib import Path
 from typing import Dict, Iterator, List, Optional
+
+import jax.monitoring
+from jax.profiler import TraceAnnotation
 
 from repro.core.obs.metrics import MetricsRegistry, merge_snapshots
 
@@ -62,6 +70,14 @@ _STAGES: Optional[Dict[str, float]] = None  # active stage collector
 _METRICS: Optional[MetricsRegistry] = None  # explicit registry override
 _TRACER: Optional["Tracer"] = None
 _WORKER_PROBED = False  # lazily checked SPAN_DIR_ENV once in this process
+_COMPILE_LISTENERS = False  # the jax_compile listeners are registered
+
+# JAX's compile phases (jaxpr tracing, lowering, the backend compile) each
+# report their duration under this prefix once they end, and a read from
+# the persistent compilation cache (inside the backend compile) under the
+# second name.
+COMPILE_PHASE_PREFIX = "/jax/core/compile/"
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 @dataclasses.dataclass
@@ -112,8 +128,12 @@ class Tracer:
         self._seq = 0
         self._metrics_seq = 0
         self._stream = None  # append-mode file (worker tracers)
+        # Profiler annotations open over a running compile phase, per
+        # (thread, event): JAX reports a phase's start and end separately.
+        self._compiling: Dict[tuple, List[TraceAnnotation]] = {}
         if self.dir is not None:
             self.dir.mkdir(parents=True, exist_ok=True)
+        _listen_for_compiles()
 
     # ------------------------------------------------------------ recording
 
@@ -143,6 +163,16 @@ class Tracer:
         self.spans.append(s)
         if self._stream is not None:
             self._write_line(s.as_dict())
+
+    def add_span(
+        self, name: str, attrs: Dict[str, object], ts: int, dur: float
+    ) -> Span:
+        """Record a span that has already ended (started at wall ``ts``,
+        lasting ``dur`` seconds), as a child of the innermost open span."""
+        s = self.open_span(name, attrs)
+        s.ts = ts
+        self.close_span(s, dur)
+        return s
 
     # --------------------------------------------------------------- files
 
@@ -328,6 +358,52 @@ def _sorted_spans(spans: List[Span]) -> List[Span]:
     return sorted(spans, key=lambda s: (s.ts, s.pid, s.span_id))
 
 
+# ----------------------------------------------------------- JAX compiles
+
+
+def _listen_for_compiles() -> None:
+    """Register the compile listeners with ``jax.monitoring``, once per
+    process; they do nothing while no tracer is active."""
+    global _COMPILE_LISTENERS
+    if _COMPILE_LISTENERS:
+        return
+    _COMPILE_LISTENERS = True
+    jax.monitoring.register_scalar_listener(_on_compile_start)
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_end)
+
+
+def _on_compile_start(event: str, value: float, **_) -> None:
+    """JAX reports each compile phase's start as a scalar (its wall
+    clock); open the phase's ``jax_compile`` profiler annotation."""
+    tracer = current_tracer()
+    if tracer is None or not event.startswith(COMPILE_PHASE_PREFIX):
+        return
+    ann = TraceAnnotation("jax_compile")
+    ann.__enter__()
+    tracer._compiling.setdefault((threading.get_ident(), event), []).append(ann)
+
+
+def _on_compile_end(event: str, duration_secs: float, **kwargs) -> None:
+    """Record one ``jax_compile`` span at ``[now - duration, now]`` per
+    compile phase and per persistent-cache read, with the event name (and
+    the function JAX names) as attributes, and close the phase's
+    profiler annotation."""
+    if not (event.startswith(COMPILE_PHASE_PREFIX) or event == CACHE_READ_EVENT):
+        return
+    tracer = current_tracer()
+    if tracer is None:
+        return
+    open_ = tracer._compiling.get((threading.get_ident(), event))
+    if open_:
+        open_.pop().__exit__(None, None, None)
+    attrs: Dict[str, object] = {"event": event}
+    if "fun_name" in kwargs:
+        attrs["fun"] = kwargs["fun_name"]
+    tracer.add_span(
+        "jax_compile", attrs, time.time_ns() - int(duration_secs * 1e9), duration_secs
+    )
+
+
 # ------------------------------------------------------------ active state
 
 
@@ -417,7 +493,8 @@ def span(name: str, **attrs) -> Iterator[Optional[Span]]:
 
     Yields the open :class:`Span` so call sites can attach attributes
     discovered mid-flight (``sp.attrs["cache"] = "hit"``), or ``None``
-    when tracing is off — guard late-attr writes with ``if sp:``.
+    when tracing is off — guard late-attr writes with ``if sp:``.  The
+    block also runs inside a profiler annotation of the same name.
     """
     tracer = current_tracer()
     if tracer is None:
@@ -426,7 +503,8 @@ def span(name: str, **attrs) -> Iterator[Optional[Span]]:
     s = tracer.open_span(name, attrs)
     t0 = time.perf_counter()
     try:
-        yield s
+        with TraceAnnotation(name):
+            yield s
     finally:
         tracer.close_span(s, time.perf_counter() - t0)
 
@@ -439,31 +517,29 @@ def stage(name: str) -> Iterator[None]:
     :func:`collect_stages` collector the duration accumulates into its
     dict (bit-identical to the pre-span implementation — one
     ``perf_counter`` delta, added once).  Additionally, when a tracer is
-    active the same interval is recorded as a span of the same name (the
-    one measured duration is shared, so ``RunTrace.stage_totals()``
-    equals the collector dict exactly), and when a metrics registry is
-    active the duration feeds the ``stage.<name>`` latency histogram.
-    With none of the three active this is a no-op.
+    active the same interval is recorded as a span of the same name,
+    inside a profiler annotation of that name (the one measured duration
+    is shared, so ``RunTrace.stage_totals()`` equals the collector dict
+    exactly).  With neither active this is a no-op.
     """
     tracer = current_tracer()
-    reg = _METRICS if _METRICS is not None else (
-        tracer.metrics if tracer is not None else None
-    )
-    if _STAGES is None and tracer is None and reg is None:
+    if _STAGES is None and tracer is None:
         yield
         return
     s = tracer.open_span(name, {}) if tracer is not None else None
     t0 = time.perf_counter()
     try:
-        yield
+        if s is None:
+            yield
+        else:
+            with TraceAnnotation(name):
+                yield
     finally:
         dt = time.perf_counter() - t0
         if s is not None:
             tracer.close_span(s, dt)
         if _STAGES is not None:
             _STAGES[name] = _STAGES.get(name, 0.0) + dt
-        if reg is not None:
-            reg.observe(f"stage.{name}", dt)
 
 
 @contextlib.contextmanager

@@ -41,7 +41,8 @@ def cache_pass_pallas(
     :func:`repro.memsim.engine.cache_pass`, including the canonical
     :class:`~repro.memsim.engine.CacheState` carry for chunked passes.
     """
-    from repro.memsim import engine  # lazy: avoids import cycle
+    from repro.core.obs import spans as obs  # lazy: avoids import cycle
+    from repro.memsim import engine
 
     if len(blocks) == 0:
         hits = np.zeros(0, dtype=bool)
@@ -51,17 +52,21 @@ def cache_pass_pallas(
         return hits, engine.CacheState(st.tags.copy(), st.age.copy())
     if interpret is None:
         interpret = interpret_mode()
-    padded, order, col, row = engine.group_by_set(blocks, sets)
-    st = state if state is not None else engine.init_state(sets, ways)
-    hits, tags1, age1 = lru_hits_carry(
-        jnp.asarray(padded),
-        jnp.asarray(st.tags),
-        jnp.asarray(st.age),
-        set_tile=set_tile or LANES,
-        interpret=interpret,
-    )
-    out = np.zeros(len(blocks), dtype=bool)
-    out[order] = np.asarray(hits)[col, row].astype(bool)
-    if not return_state:
-        return out
-    return out, engine.canonicalize_state(np.asarray(tags1), np.asarray(age1))
+    with obs.span("cache_pass.group"):
+        padded, order, col, row = engine.group_by_set(blocks, sets)
+        st = state if state is not None else engine.init_state(sets, ways)
+    with obs.span("cache_pass.device"):
+        hits, tags1, age1 = lru_hits_carry(
+            jnp.asarray(padded),
+            jnp.asarray(st.tags),
+            jnp.asarray(st.age),
+            set_tile=set_tile or LANES,
+            interpret=interpret,
+        )
+        hits = np.asarray(hits)
+    with obs.span("cache_pass.scatter"):
+        out = np.zeros(len(blocks), dtype=bool)
+        out[order] = hits[col, row].astype(bool)
+        if not return_state:
+            return out
+        return out, engine.canonicalize_state(np.asarray(tags1), np.asarray(age1))

@@ -277,17 +277,22 @@ def cache_pass_set_parallel(
     if cells > max(_PAD_FACTOR * len(blocks), _PAD_FLOOR_CELLS):
         # bit-identical fallback (canonical states compose across engines)
         return scan_cache.cache_pass(blocks, sets, ways, state, return_state)
-    padded, order, col, row = group_by_set(blocks, sets)
-    st = state if state is not None else init_state(sets, ways)
-    hits, tags1, age1 = _batched_pass(sets, ways)(
-        jnp.asarray(padded), jnp.asarray(st.tags), jnp.asarray(st.age)
-    )
-    hits = np.asarray(hits)
-    out = np.zeros(len(blocks), dtype=bool)
-    out[order] = hits[col, row]
-    if not return_state:
-        return out
-    return out, canonicalize_state(np.asarray(tags1), np.asarray(age1))
+    from repro.core.obs import spans as obs  # lazy: avoids import cycle
+
+    with obs.span("cache_pass.group"):
+        padded, order, col, row = group_by_set(blocks, sets)
+        st = state if state is not None else init_state(sets, ways)
+    with obs.span("cache_pass.device"):
+        hits, tags1, age1 = _batched_pass(sets, ways)(
+            jnp.asarray(padded), jnp.asarray(st.tags), jnp.asarray(st.age)
+        )
+        hits = np.asarray(hits)
+    with obs.span("cache_pass.scatter"):
+        out = np.zeros(len(blocks), dtype=bool)
+        out[order] = hits[col, row]
+        if not return_state:
+            return out
+        return out, canonicalize_state(np.asarray(tags1), np.asarray(age1))
 
 
 def _fused_select_pass(sets: int, ways: int):

@@ -39,6 +39,14 @@ def _stage(name: str):
     return stage(name)
 
 
+def _span(name: str):
+    """A span of the scoring pass (``prefetch.merge``/``prefetch.classify``),
+    imported lazily for the same reason as :func:`_stage`."""
+    from repro.core.obs.spans import span
+
+    return span(name)
+
+
 def _count_launch(batched: int = 0) -> None:
     """Metrics counters for fused-pass dispatches (no-op when obs is off):
     ``fused.launches`` counts scan launches, ``fused.batched_streams`` the
@@ -357,7 +365,8 @@ def simulate_with_prefetch(
             else None,
         )
 
-    merged = _merge_prefetch_stream(profile, pf_blocks, pf_pos, pf_issuer)
+    with _span("prefetch.merge"):
+        merged = _merge_prefetch_stream(profile, pf_blocks, pf_pos, pf_issuer)
     mblocks_s = merged["mblocks_s"]
     # Scoring a single stream runs the per-level cascade under every
     # engine: the L2 substream has no L1-filterable runs to collapse, so
@@ -371,9 +380,10 @@ def simulate_with_prefetch(
         llc_hit = cache_pass(
             mblocks_s[~hit], cfg.llc.sets, cfg.llc.ways
         )
-    return _finish_prefetch_outcome(
-        profile, merged, hit, llc_hit, metadata_bytes, keep_llc_stream
-    )
+    with _span("prefetch.classify"):
+        return _finish_prefetch_outcome(
+            profile, merged, hit, llc_hit, metadata_bytes, keep_llc_stream
+        )
 
 
 def simulate_with_prefetch_batch(
@@ -402,9 +412,11 @@ def simulate_with_prefetch_batch(
             for (b, p, issuer), m in zip(streams, meta)
         ]
     cfg = profile.cfg
-    merged = [
-        _merge_prefetch_stream(profile, b, p, issuer) for b, p, issuer in streams
-    ]
+    with _span("prefetch.merge"):
+        merged = [
+            _merge_prefetch_stream(profile, b, p, issuer)
+            for b, p, issuer in streams
+        ]
     with _stage("cache_pass[l2]"):
         l2_hits = cache_pass_batch(
             [m["mblocks_s"] for m in merged], cfg.l2.sets, cfg.l2.ways
@@ -417,10 +429,11 @@ def simulate_with_prefetch_batch(
             cfg.llc.ways,
         )
         _count_launch(batched=len(streams))
-    return [
-        _finish_prefetch_outcome(profile, m, h, lh, mb, keep_llc_stream)
-        for m, h, lh, mb in zip(merged, l2_hits, llc_hits, meta)
-    ]
+    with _span("prefetch.classify"):
+        return [
+            _finish_prefetch_outcome(profile, m, h, lh, mb, keep_llc_stream)
+            for m, h, lh, mb in zip(merged, l2_hits, llc_hits, meta)
+        ]
 
 
 def _merge_prefetch_stream(

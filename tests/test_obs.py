@@ -154,7 +154,7 @@ def test_merge_snapshots_sums_counters_and_merges_histograms():
 
 
 def test_metrics_helpers_route_to_active_registry():
-    with obs.metrics_registry() as reg:
+    with obs.trace() as t, obs.metrics_registry() as reg:
         obs.inc("c", 2)
         obs.observe("h", 0.1)
         obs.set_gauge("g", 7)
@@ -162,7 +162,7 @@ def test_metrics_helpers_route_to_active_registry():
             pass
     assert reg.counter("c") == 2.0
     assert reg.gauges["g"] == 7.0
-    assert reg.histograms["stage.timed"]["count"] == 1
+    assert [s.name for s in t.spans] == ["timed"]
     obs.inc("c")  # registry closed: no-op
     assert reg.counter("c") == 2.0
 
@@ -350,6 +350,144 @@ def test_experiment_tracing_serial_matches_workers2(tmp_path):
     assert pooled.telemetry["trace_id"] == tp.trace_id
     assert pooled.telemetry["manifest"]["trace_schema"] == obs.TRACE_SCHEMA
     assert pooled.telemetry["workload_cache"]["hits"] >= 0
+
+
+# ------------------------------------------- spans inside scoring and passes
+
+# The span each new span opens inside: scoring pieces inside ``score``
+# (the next-line baseline's merge and classification run in
+# ``demand_sim``), the parts of a cache pass inside its ``cache_pass[...]``.
+_NEW_SPAN_PARENTS = {
+    "score.generate[amc]": {"score"},
+    "score.generate[vldp]": {"score"},
+    "score.evaluate": {"score"},
+    "prefetch.merge": {"score", "demand_sim"},
+    "prefetch.classify": {"score", "demand_sim"},
+    "cache_pass.group": {"cache_pass[l1]", "cache_pass[l2]", "cache_pass[llc]"},
+    "cache_pass.device": {"cache_pass[l1]", "cache_pass[l2]", "cache_pass[llc]"},
+    "cache_pass.scatter": {"cache_pass[l1]", "cache_pass[l2]", "cache_pass[llc]"},
+}
+
+
+def _tiny_bfs_experiment():
+    from repro.core import Experiment
+
+    return Experiment(kernels=["bfs"], datasets=["tiny"], prefetchers=["amc", "vldp"])
+
+
+@pytest.mark.parametrize("engine", ["set_parallel", "pallas"])
+def test_scoring_and_cache_pass_spans_open_inside_their_layers(engine):
+    from repro.memsim import use_engine
+
+    with use_engine(engine), obs.trace() as t:
+        _tiny_bfs_experiment().run(workers=1)
+    by_id = {s.span_id: s for s in t.spans}
+    parents: dict = {}
+    for s in t.spans:
+        if s.name in _NEW_SPAN_PARENTS:
+            parents.setdefault(s.name, set()).add(by_id[s.parent_id].name)
+    assert set(parents) == set(_NEW_SPAN_PARENTS)
+    for name, seen in parents.items():
+        assert seen <= _NEW_SPAN_PARENTS[name], name
+    assert {"score", "demand_sim"} <= parents["prefetch.merge"]
+    # Each pass is split into its three parts, in order, with nothing else.
+    for s in t.spans:
+        if s.name.startswith("cache_pass["):
+            kids = [k.name for k in t.spans
+                    if k.parent_id == s.span_id and k.name != "jax_compile"]
+            assert kids == ["cache_pass.group", "cache_pass.device",
+                            "cache_pass.scatter"]
+
+
+def test_a_first_compile_is_a_span_and_a_repeat_call_is_not():
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: x * 3 + 1)
+    with obs.trace() as t:
+        with obs.span("outer"):
+            f(jnp.ones(37)).block_until_ready()
+        first = list(t.spans)
+        f(jnp.ones(37)).block_until_ready()
+    compiles = [s for s in first if s.name == "jax_compile"]
+    events = {s.attrs["event"] for s in compiles}
+    assert obs.COMPILE_PHASE_PREFIX + "backend_compile_duration" in events
+    assert all(e.startswith(obs.COMPILE_PHASE_PREFIX) for e in events)
+    outer = next(s for s in first if s.name == "outer")
+    for s in compiles:
+        assert s.parent_id == outer.span_id and s.dur > 0
+        assert outer.ts <= s.ts and s.ts + s.dur * 1e9 <= outer.ts + outer.dur * 1e9 + 1e6
+    assert [s.name for s in t.spans[len(first):]] == []
+
+
+def test_new_span_sites_record_nothing_without_a_tracer(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from repro.memsim import use_engine
+
+    entered = []
+
+    class Annotation:
+        def __init__(self, name, **kwargs):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(obs, "TraceAnnotation", Annotation)
+    with use_engine("set_parallel"), collect_stages() as times:
+        _tiny_bfs_experiment().run(workers=1)
+        jax.jit(lambda x: x - 5)(jnp.ones(41)).block_until_ready()
+    assert obs.current_tracer() is None
+    assert entered == []
+    # The new sites are spans, not stages: the stage dict keeps its keys.
+    assert not [k for k in times if "." in k or k == "jax_compile"]
+
+    with use_engine("set_parallel"), obs.trace() as t:
+        _tiny_bfs_experiment().run(workers=1)
+    assert sorted(entered) == sorted(s.name for s in t.spans)
+
+
+def _host_events(xplane_path: str):
+    """``{name: [start_ns]}`` of the host events of a profiler trace, on
+    the wall clock (``time.time_ns``)."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(xplane_path)
+    start = dict(data.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"
+    ]
+    out: dict = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(start + e.start_ns)
+    return out
+
+
+def test_program_spans_land_on_the_profiler_host_plane(tmp_path):
+    import glob
+
+    import jax
+    from repro.memsim import use_engine
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # program spans only, not every call
+    with use_engine("set_parallel"), jax.profiler.trace(
+        str(tmp_path), profiler_options=options
+    ), obs.trace() as t:
+        _tiny_bfs_experiment().run(workers=1)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = _host_events(path)
+    names = {s.name for s in t.spans}
+    assert {"score", "score.evaluate", "cache_pass.device", "jax_compile"} <= names
+    for s in t.spans:
+        starts = host.get(s.name, [])
+        assert any(abs(h - s.ts) < 1_000_000 for h in starts), (s.name, s.attrs)
 
 
 # ----------------------------------------------------------- trace export
