@@ -13,8 +13,10 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
+from repro.memsim import classify_device
 from repro.memsim.config import HierarchyConfig
 from repro.memsim.engine import (
     CacheState,
@@ -488,6 +490,13 @@ def _merge_prefetch_stream(
     )
 
 
+def _classify_on_device() -> bool:
+    """Classification runs as the jitted chain program on a TPU (the
+    host idles the chip otherwise) and in numpy elsewhere, where XLA:CPU
+    is slower than numpy; the ``reference`` engine keeps the host oracle."""
+    return jax.default_backend() == "tpu" and current_engine() != "reference"
+
+
 def _finish_prefetch_outcome(
     profile: DemandProfile,
     merged: dict,
@@ -499,59 +508,86 @@ def _finish_prefetch_outcome(
     """Classify + unmerge one scored stream back into a
     :class:`PrefetchOutcome` (``hit`` over the merged stream, ``llc_hit``
     over its L2-miss substream — however the passes were dispatched)."""
-    cfg = profile.cfg
-    mblocks_s = merged["mblocks_s"]
-    mpos_s = merged["mpos_s"]
-    m_is_pf_s = merged["m_is_pf_s"]
+    l2 = _classify_l2(profile, merged, hit)
+
+    # The LLC stream is the merged stream's L2 misses in order; its demand
+    # events appear in merged order == pos order == demand-substream order,
+    # and its prefetches in prefetch order.
+    llc_sel = ~hit
+    llc_is_pf = merged["m_is_pf_s"][llc_sel]
+    return PrefetchOutcome(
+        pf_pos=merged["pf_pos"],
+        pf_issuer=merged["pf_issuer"],
+        pf_redundant=l2.pf_redundant,
+        pf_no_future=l2.pf_no_future,
+        pf_llc_in_dram=(~llc_hit)[llc_is_pf],
+        pf_llc_in_pos=merged["pf_pos"][~l2.pf_l2_hit],
+        demand_l2_hit=l2.demand_l2_hit,
+        demand_useful=l2.demand_useful,
+        demand_late=l2.demand_late,
+        demand_fill_issuer=l2.demand_fill_issuer,
+        demand_llc_hit=llc_hit[~llc_is_pf],
+        evicted_early_total=int(l2.pf_early.sum()),
+        pf_early=l2.pf_early,
+        metadata_bytes=metadata_bytes,
+        llc_in_blocks=merged["mblocks_s"][llc_sel] if keep_llc_stream else None,
+        llc_in_pos2=merged["mpos_s"][llc_sel] if keep_llc_stream else None,
+        llc_in_is_pf=llc_is_pf if keep_llc_stream else None,
+    )
+
+
+def _classify_l2(
+    profile: DemandProfile, merged: dict, hit: np.ndarray
+) -> classify_device.L2Outcome:
+    """The L2 classification of one merged stream, on the device where
+    :func:`_classify_on_device` says so and the stream fits int32."""
+    fill_window = 2 * profile.cfg.pf_fill_window
+    mblocks_s, mpos_s = merged["mblocks_s"], merged["mpos_s"]
+    if _classify_on_device() and classify_device.fits_int32(
+        int(mblocks_s.max()), int(mpos_s[-1]), fill_window
+    ):
+        from repro.core.obs.spans import inc
+
+        inc("prefetch.classify_device", len(mblocks_s))
+        return classify_device.classify_chains(
+            mblocks_s,
+            mpos_s,
+            merged["m_issuer"],
+            hit,
+            merged["demand_slots"],
+            profile.l2_hit,
+            fill_window,
+        )
+    return _classify_l2_on_host(profile, merged, hit, fill_window)
+
+
+def _classify_l2_on_host(
+    profile: DemandProfile, merged: dict, hit: np.ndarray, fill_window: int
+) -> classify_device.L2Outcome:
+    """The numpy form of :func:`_classify_l2`: the CPU path and the
+    oracle of the device program."""
     pf_slots = merged["pf_slots"]
     demand_slots = merged["demand_slots"]
-    pf_blocks, pf_pos = merged["pf_blocks"], merged["pf_pos"]
-
     useful, late, redundant, early, fill_origin = classify_prefetch_events(
-        mblocks_s, m_is_pf_s, mpos_s, hit, 2 * cfg.pf_fill_window
+        merged["mblocks_s"], merged["m_is_pf_s"], merged["mpos_s"], hit, fill_window
     )
-    llc_sel = ~hit
-    llc_is_pf = m_is_pf_s[llc_sel]
-    llc_pos = mpos_s[llc_sel] // 2
-
-    # Unmerge.
-    demand_l2_hit = hit[demand_slots]
-    demand_useful = useful[demand_slots]
-    demand_late = late[demand_slots]
-    pf_redundant = redundant[pf_slots]
-    pf_early = early[pf_slots]
     d_fill = fill_origin[demand_slots]
-    demand_fill_issuer = np.where(
-        d_fill >= 0, merged["m_issuer"][np.maximum(d_fill, 0)], -1
-    ).astype(np.int8)
-
-    # Demand LLC hits over demand L2 misses, in demand order: the demand
-    # events within the LLC stream appear in merged order == pos order,
-    # which equals demand-substream order (stable sort on pos).
-    demand_llc_hit = llc_hit[~llc_is_pf]
-
-    pf_no_future = _no_future_demand(
-        pf_blocks, pf_pos, profile.l2_miss_blocks, profile.l2_miss_pos
-    )
-
-    return PrefetchOutcome(
-        pf_pos=pf_pos,
-        pf_issuer=merged["pf_issuer"],
-        pf_redundant=pf_redundant,
-        pf_no_future=pf_no_future,
-        pf_llc_in_dram=(~llc_hit)[llc_is_pf],
-        pf_llc_in_pos=llc_pos[llc_is_pf],
-        demand_l2_hit=demand_l2_hit,
-        demand_useful=demand_useful,
-        demand_late=demand_late,
-        demand_fill_issuer=demand_fill_issuer,
-        demand_llc_hit=demand_llc_hit,
-        evicted_early_total=int(early.sum()),
-        pf_early=pf_early,
-        metadata_bytes=metadata_bytes,
-        llc_in_blocks=mblocks_s[llc_sel] if keep_llc_stream else None,
-        llc_in_pos2=mpos_s[llc_sel] if keep_llc_stream else None,
-        llc_in_is_pf=llc_is_pf if keep_llc_stream else None,
+    return classify_device.L2Outcome(
+        demand_l2_hit=hit[demand_slots],
+        demand_useful=useful[demand_slots],
+        demand_late=late[demand_slots],
+        demand_fill_issuer=np.where(
+            d_fill >= 0, merged["m_issuer"][np.maximum(d_fill, 0)], -1
+        ).astype(np.int8),
+        pf_l2_hit=hit[pf_slots],
+        pf_redundant=redundant[pf_slots],
+        pf_early=early[pf_slots],
+        pf_no_future=_no_future_demand(
+            merged["pf_blocks"],
+            merged["pf_pos"],
+            profile.l2_miss_blocks,
+            profile.l2_miss_pos,
+        ),
     )
 
 

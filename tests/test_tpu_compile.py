@@ -1,4 +1,5 @@
-"""The Pallas cache kernels compile for a TPU v5e.
+"""The Pallas cache kernels and the classification program compile for a
+TPU v5e.
 
 Interpret mode (the other kernel tests) checks what the kernels compute;
 only the TPU compiler checks that they can run on the chip at all: tiling
@@ -111,3 +112,24 @@ def test_fused_levels_compiles(shape, cfg, steps):
         shape(steps, groups), levels, *state
     ).compile()
     _assert_kernel(compiled)
+
+
+def test_classify_program_compiles(one_chip, no_compile_cache):
+    """The chain-classification program (one sort, shifted scans, one
+    scatter) lowers for the chip; at 2^12 events, since the TPU compiler
+    takes tens of seconds for a sort of 2^21."""
+    from repro.memsim.classify_device import _classify_program
+
+    def arg(dtype, dims=(1 << 12,)):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = _classify_program.lower(
+        arg(jnp.int32),
+        arg(jnp.int32),
+        arg(jnp.bool_),
+        arg(jnp.bool_),
+        arg(jnp.int8),
+        arg(jnp.int32, ()),
+    ).compile()
+    text = compiled.as_text()
+    assert " sort(" in text and " scatter(" in text
