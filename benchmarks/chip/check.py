@@ -5,12 +5,16 @@ Three numbers, each an exact comparison with the limit 0:
 - ``trace_mismatch``: accesses whose line, or iteration, differs between
   the simulator's emitted trace and the reference's, plus the difference
   in length and in the first scored position;
-- ``hit_mismatch``: L1, L2 and LLC demand hit bits that differ;
+- ``hit_mismatch``: L1, L2 and LLC demand hit bits that differ; for a
+  sharded job, which keeps no per-access masks, the gaps between the demand
+  counts over the scored window that each row carries (``DEMAND_COUNTS``)
+  and the reference's;
 - ``row_gap``: the largest relative gap over every field of every scored
   row (speedup, coverage, accuracy, counts and traffic).
 
 The reference takes the configuration and the job's seed, and for each row
 the prefetch stream that the evaluated prefetcher issued on the timed path.
+Its cache passes run split by set over ``groups`` (``reference/cache.py``).
 """
 from __future__ import annotations
 
@@ -19,7 +23,7 @@ import math
 
 import numpy as np
 
-from reference import graphs, scoring
+from reference import cache, graphs, scoring
 
 LIMITS = {"trace_mismatch": 0, "hit_mismatch": 0, "row_gap": 0}
 ROW_FIELDS = (
@@ -40,6 +44,9 @@ ROW_FIELDS = (
     "dram_demand",
     "dram_total",
 )
+# What a sharded job's rows say of its demand misses in the scored window:
+# L2 misses beside next-line alone, and DRAM demand beside the prefetcher.
+DEMAND_COUNTS = ("baseline_l2_misses", "dram_demand")
 
 
 def _differ(a: np.ndarray, b: np.ndarray) -> int:
@@ -60,9 +67,12 @@ def _gap(got: float, want: float) -> float:
 class Reference:
     """The configuration's plain reference, with its base graph made once."""
 
-    def __init__(self, config: dict, policy: str = "lru"):
+    def __init__(
+        self, config: dict, policy: str = "lru", groups: cache.SetGroups = cache.SERIAL
+    ):
         self.config = config
         self.policy = policy
+        self.groups = groups
         self.workload = importlib.import_module(f"reference.{config['reference']}")
         self._graph = None
         self._last = None  # (seed, trace, demand) of the last workload
@@ -78,13 +88,15 @@ class Reference:
         since a job sweep scores one workload many times)."""
         if self._last is None or self._last[0] != seed:
             trace = self.workload.trace(self.config, seed, self.graph)
-            d = scoring.demand(trace["blocks"], self.config["hierarchy"], self.policy)
+            d = scoring.demand(
+                trace["blocks"], self.config["hierarchy"], self.policy, self.groups
+            )
             self._last = (seed, trace, d)
         return self._last[1], self._last[2]
 
     def row(self, d: scoring.Demand, stream: tuple, eval_from: int) -> dict:
         h, tm = self.config["hierarchy"], self.config["timing"]
-        return scoring.score(d, stream, eval_from, h, tm)
+        return scoring.score(d, stream, eval_from, h, tm, self.groups)
 
 
 def compare(jobs: list, ref: Reference, truth: Reference | None = None) -> dict:
@@ -92,9 +104,10 @@ def compare(jobs: list, ref: Reference, truth: Reference | None = None) -> dict:
 
     Each job is a dict with ``workloads`` (per workload: ``seed``,
     ``block``, ``iter_id``, ``eval_from``, ``l1_hit``, ``l2_hit``,
-    ``llc_hit`` and ``rows``, a list of ``(row, stream)``).  With ``truth``
-    given, ``ref`` stands in for the timed path (the control): its answers
-    replace the program's and ``truth`` judges them.
+    ``llc_hit`` and ``rows``, a list of ``(row, stream)``; a sharded job's
+    workload has no ``*_hit`` masks).  With ``truth`` given, ``ref`` stands
+    in for the timed path (the control): its answers replace the program's
+    and ``truth`` judges them, in the same form as the job's.
     """
     out = {"trace_mismatch": 0, "hit_mismatch": 0, "row_gap": 0.0}
     for job in jobs:
@@ -110,10 +123,15 @@ def compare(jobs: list, ref: Reference, truth: Reference | None = None) -> dict:
                 + _differ(got["iter_id"], want["iter_id"])
                 + abs(int(got["eval_from"]) - int(want["eval_from"]))
             )
-            for level in ("l1_hit", "l2_hit", "llc_hit"):
-                out["hit_mismatch"] += _differ(got[level], getattr(d, level))
+            masks = "l1_hit" in w
+            if masks:
+                for level in ("l1_hit", "l2_hit", "llc_hit"):
+                    out["hit_mismatch"] += _differ(got[level], getattr(d, level))
             for row, stream in zip(got["rows"], streams):
                 expect = (truth or ref).row(d, stream, int(want["eval_from"]))
+                if not masks:
+                    for field in DEMAND_COUNTS:
+                        out["hit_mismatch"] += abs(int(row[field]) - int(expect[field]))
                 for field in ROW_FIELDS:
                     out["row_gap"] = max(
                         out["row_gap"], _gap(float(row[field]), float(expect[field]))

@@ -33,6 +33,14 @@ def layout(n: int, m: int) -> np.ndarray:
     return np.array(bases, dtype=np.int64)
 
 
+def edges_of(g: graphs.Graph, frontier: np.ndarray) -> np.ndarray:
+    """The out-edge indices of ``frontier``, vertex by vertex in its order."""
+    lo = g.offsets[frontier]
+    deg = g.offsets[frontier + 1] - lo
+    before = np.cumsum(deg) - deg  # edges of the earlier frontier vertices
+    return np.repeat(lo - before, deg) + np.arange(int(deg.sum()), dtype=np.int64)
+
+
 def levels(g: graphs.Graph, root: int, present: np.ndarray) -> list:
     """BFS frontiers, each in ascending vertex order."""
     visited = np.zeros(g.num_vertices, dtype=bool)
@@ -42,8 +50,7 @@ def levels(g: graphs.Graph, root: int, present: np.ndarray) -> list:
     while len(frontier) and len(out) < MAX_LEVELS:
         out.append(frontier)
         reached = np.zeros(g.num_vertices, dtype=bool)
-        for v in frontier.tolist():
-            reached[g.neighbors[g.offsets[v] : g.offsets[v + 1]]] = True
+        reached[g.neighbors[edges_of(g, frontier)]] = True
         new = reached & ~visited & present
         visited |= new
         frontier = np.flatnonzero(new).astype(np.int64)
@@ -51,17 +58,20 @@ def levels(g: graphs.Graph, root: int, present: np.ndarray) -> list:
 
 
 def emit(g: graphs.Graph, frontier: np.ndarray, bases: np.ndarray) -> np.ndarray:
-    """Byte addresses of one push level."""
-    parts = []
-    for v in frontier.tolist():
-        lo, hi = int(g.offsets[v]), int(g.offsets[v + 1])
-        head = bases[[F, T, V]] + v * np.array(ELEM_BYTES[:3], dtype=np.int64)
-        edges = np.arange(lo, hi, dtype=np.int64)
-        body = np.empty(2 * (hi - lo), dtype=np.int64)
-        body[0::2] = bases[N] + 4 * edges
-        body[1::2] = bases[P] + 8 * g.neighbors[lo:hi].astype(np.int64)
-        parts += [head, body]
-    return np.concatenate(parts) if parts else np.zeros(0, np.int64)
+    """Byte addresses of one push level: per frontier vertex, in order, its
+    three head reads and then one (N, P) pair per out-edge."""
+    deg = g.offsets[frontier + 1] - g.offsets[frontier]
+    size = 3 + 2 * deg
+    start = np.cumsum(size) - size
+    out = np.empty(int(size.sum()), dtype=np.int64)
+    for k in (F, T, V):
+        out[start + k] = bases[k] + frontier * ELEM_BYTES[k]
+    edges = edges_of(g, frontier)
+    before = np.cumsum(deg) - deg
+    body = np.repeat(start + 3 - 2 * before, deg) + 2 * np.arange(len(edges))
+    out[body] = bases[N] + 4 * edges
+    out[body + 1] = bases[P] + 8 * g.neighbors[edges].astype(np.int64)
+    return out
 
 
 def trace(config: dict, seed: int, base_graph: graphs.Graph) -> dict:

@@ -2,7 +2,8 @@
 
 This is the benchmark's own data generator: the same draws, in the same
 order, as the graph generators that the simulator uses for its named
-datasets (R-MAT, configuration-model power law) and for the paper's §VI
+datasets (R-MAT, configuration-model power law, road lattice) and for the
+paper's §VI
 vertex churn.  The reference runs on these graphs; the simulator makes its
 own from the dataset name, so a change to the simulator's data shows up as
 a trace that differs from this one.
@@ -95,12 +96,33 @@ def powerlaw(n: int, m: int, gamma: float, seed: int) -> Graph:
     return _trim(from_edges(src, dst, n), m, rng, n)
 
 
+def road(n: int, shortcut_frac: float, seed: int) -> Graph:
+    """A square lattice of ``int(sqrt(n))**2`` vertices, each lattice edge
+    in both directions, plus ``shortcut_frac`` of the vertex count in
+    directed shortcuts between uniform endpoints."""
+    rng = np.random.default_rng(seed)
+    side = int(np.sqrt(n))
+    n = side * side
+    idx = np.arange(n).reshape(side, side)
+    right = np.stack([idx[:, :-1].ravel(), idx[:, 1:].ravel()], axis=1)
+    down = np.stack([idx[:-1, :].ravel(), idx[1:, :].ravel()], axis=1)
+    lattice = np.concatenate([right, down])
+    edges = np.concatenate([lattice, lattice[:, ::-1]])
+    n_short = int(n * shortcut_frac)
+    src = rng.integers(0, n, size=n_short)
+    dst = rng.integers(0, n, size=n_short)
+    edges = np.concatenate([edges, np.stack([src, dst], axis=1)])
+    return from_edges(edges[:, 0], edges[:, 1], n)
+
+
 def make_graph(spec: dict) -> Graph:
     """The graph a configuration's ``graph`` entry describes."""
     if spec["kind"] == "rmat":
         return rmat(spec["n"], spec["m"], spec["a"], spec["seed"])
     if spec["kind"] == "powerlaw":
         return powerlaw(spec["n"], spec["m"], spec["gamma"], spec["seed"])
+    if spec["kind"] == "road":
+        return road(spec["n"], spec["shortcut_frac"], spec["seed"])
     raise ValueError(f"unknown graph kind {spec['kind']!r}")
 
 

@@ -39,9 +39,14 @@ class Demand:
         return self.l2_pos[~self.l2_hit]
 
 
-def demand(blocks: np.ndarray, hierarchy: dict, policy: str = "lru") -> Demand:
+def demand(
+    blocks: np.ndarray,
+    hierarchy: dict,
+    policy: str = "lru",
+    groups: cache.SetGroups = cache.SERIAL,
+) -> Demand:
     levels = [_geometry(hierarchy[k]) for k in ("l1", "l2", "llc")]
-    l1, l2, llc = cache.hierarchy(blocks, levels, policy)
+    l1, l2, llc = cache.hierarchy(blocks, levels, policy, groups)
     return Demand(blocks, l1, np.flatnonzero(~l1).astype(np.int64), l2, llc, policy)
 
 
@@ -65,6 +70,7 @@ def outcome(
     pf_issuer: np.ndarray,
     hierarchy: dict,
     policy: str = "lru",
+    groups: cache.SetGroups = cache.SERIAL,
 ) -> dict:
     """Demand and prefetch events of one run with prefetching, per event."""
     order = np.argsort(pf_pos, kind="stable")
@@ -77,27 +83,31 @@ def outcome(
     issuer = np.concatenate([np.full(nd, -1, np.int8), pf_issuer.astype(np.int8)])
     issuer = issuer[merged]
     pos2 = pos2[merged]
-    l2 = cache.prefetch_pass(
+    l2_sets, l2_ways = _geometry(hierarchy["l2"])
+    l2 = groups.run(
+        cache.prefetch_pass,
+        l2_sets,
         blocks,
         is_pf,
         pos2,
         issuer,
-        *_geometry(hierarchy["l2"]),
-        2 * hierarchy["pf_fill_window"],
-        policy,
+        args=(l2_ways, 2 * hierarchy["pf_fill_window"], policy),
     )
     miss = ~l2["hit"]
-    llc_hit = cache.hits(blocks[miss], *_geometry(hierarchy["llc"]), policy)
+    llc_sets, llc_ways = _geometry(hierarchy["llc"])
+    llc_hit = groups.run(cache.hits, llc_sets, blocks[miss], args=(llc_ways, policy))
     llc_is_pf = is_pf[miss]
     dem = ~is_pf
-    # Prefetches whose block no later baseline L2 miss demands.
-    future = {}
-    for b, p in zip(d.l2_blocks[~d.l2_hit].tolist(), d.l2_miss_pos.tolist()):
-        future[b] = p  # positions ascend: keeps the last miss of each block
-    no_future = np.array(
-        [future.get(b, -1) <= p for b, p in zip(pf_blocks.tolist(), pf_pos.tolist())],
-        dtype=bool,
-    )
+    # Prefetches whose block no later baseline L2 miss demands: the last
+    # miss position of each block (positions ascend), looked up per prefetch.
+    # A block that never misses reads -1.
+    miss_blocks = d.l2_blocks[~d.l2_hit]
+    known, last = np.unique(miss_blocks[::-1], return_index=True)
+    last_pos = np.append(d.l2_miss_pos[len(miss_blocks) - 1 - last], -1)
+    at = np.searchsorted(known, pf_blocks)
+    inside = at < len(known)
+    inside[inside] = known[at[inside]] == pf_blocks[inside]
+    no_future = last_pos[np.where(inside, at, len(known))] <= pf_pos
     pf_sel = is_pf
     return dict(
         pf_pos=pf_pos,
@@ -177,7 +187,14 @@ def _cycles(d: Demand, o: dict, t0: int, h: dict, tm: dict, meta_lines: int):
     return cycles, dict(l2_misses=l2_misses, dram_demand=dram_demand, dram_total=dram_total)
 
 
-def score(d: Demand, stream: tuple, t0: int, hierarchy: dict, timing: dict) -> dict:
+def score(
+    d: Demand,
+    stream: tuple,
+    t0: int,
+    hierarchy: dict,
+    timing: dict,
+    groups: cache.SetGroups = cache.SERIAL,
+) -> dict:
     """The row of one evaluated prefetcher: ``stream`` is its
     ``(blocks, pos, metadata_bytes)``, scored beside next-line from ``t0``
     under the replacement policy ``d`` was simulated with."""
@@ -185,7 +202,13 @@ def score(d: Demand, stream: tuple, t0: int, hierarchy: dict, timing: dict) -> d
     nl_blocks, nl_pos = nextline(d)
     if d.nextline_outcome is None:
         d.nextline_outcome = outcome(
-            d, nl_blocks, nl_pos, np.zeros(len(nl_blocks), np.int8), hierarchy, policy
+            d,
+            nl_blocks,
+            nl_pos,
+            np.zeros(len(nl_blocks), np.int8),
+            hierarchy,
+            policy,
+            groups,
         )
     base_o = d.nextline_outcome
     x_blocks, x_pos, meta_bytes = stream
@@ -198,6 +221,7 @@ def score(d: Demand, stream: tuple, t0: int, hierarchy: dict, timing: dict) -> d
         np.concatenate([np.zeros(len(nl_blocks), np.int8), np.ones(len(x_blocks), np.int8)]),
         hierarchy,
         policy,
+        groups,
     )
     base = _baseline_counts(d, t0)
     base_cycles, base_counts = _cycles(d, base_o, t0, hierarchy, timing, 0)

@@ -12,6 +12,8 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 The window is a closed loop of jobs, each one architect's
 ``Experiment(...).run(workers=1)`` from a workload spec to scored rows;
 jobs start until ``--seconds`` have passed and the job in flight finishes.
+A ``sharded`` traffic scores a ``ShardedSpec`` from a shard store that
+set-up builds under a temporary directory and the run removes at its end.
 ``accesses_per_s`` is the simulated accesses of every job over the time
 to the last job's end, with the device queue drained at each timestamp.
 With ``--trace 1`` the window also records the program's stage spans and
@@ -53,6 +55,7 @@ import numpy as np  # noqa: E402
 import check  # noqa: E402
 import xplane  # noqa: E402
 from layers import Layers  # noqa: E402
+from reference.cache import SetGroups, default_workers  # noqa: E402
 
 
 class NoChip(RuntimeError):
@@ -97,7 +100,7 @@ def load_cell(checkout: Path, workload: str) -> Cell:
         raise SystemExit(f"unknown workload {workload!r}; have {sorted(entries)}")
     w = entries[workload]
     (cfg,) = [c for c in bench["configs"] if c["name"] == w["config"]]
-    return Cell(
+    cell = Cell(
         name=workload,
         chips=int(w["chips"]),
         config=json.loads((checkout / cfg["file"]).read_text()),
@@ -106,6 +109,29 @@ def load_cell(checkout: Path, workload: str) -> Cell:
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
         root=root,
     )
+    if cell.traffic["workload"] == "sharded":
+        derived = {
+            p["name"]
+            for index in range(len(cell.traffic["jobs"]))
+            for p in Traffic.chosen(cell.config, cell.traffic, index)
+        } & SHARDED_DERIVED
+        if derived:
+            raise BadCell(
+                f"{workload}: the sharded path derives the stream of "
+                f"{sorted(derived)} itself and never issues it through the "
+                "prefetcher, so the check could not re-score it; leave it out "
+                "of a sharded traffic"
+            )
+    return cell
+
+
+class BadCell(ValueError):
+    """A cell whose traffic the harness cannot run and check."""
+
+
+# Prefetchers whose stream the sharded scorer derives from the demand stream
+# by name (``core/exec/sharded.py``), never calling their generator.
+SHARDED_DERIVED = {"nextline2"}
 
 
 # ----------------------------------------------------------------- jobs
@@ -121,7 +147,7 @@ class Job:
     index: int
     accesses: int
     result: object  # the ExperimentResult
-    streams: Dict[tuple, tuple]  # (workload seed, prefetcher) -> stream
+    streams: Dict[tuple, tuple]  # (job's spec, prefetcher) -> stream
 
 
 class Traffic:
@@ -129,14 +155,21 @@ class Traffic:
 
     ``workload``: ``per_job`` builds each job's workload fresh from a seed
     derived from (run seed, job index); ``per_run`` builds one workload in
-    set-up and every job scores against it.  ``jobs`` is cycled in order;
-    each entry names its prefetchers (``"config"`` for the configuration's
-    own list).
+    set-up and every job scores against it; ``sharded`` builds the shard
+    store of one ``ShardedSpec`` (``shard_accesses`` to a shard) in set-up,
+    under a temporary directory that :meth:`close` removes, and every job
+    scores it through the sharded streaming path.  ``jobs`` is cycled in
+    order; each entry names its prefetchers (``"config"`` for the
+    configuration's own list).
     """
 
     def __init__(self, config: dict, traffic: dict, seed: int):
         self.config, self.traffic, self.seed = config, traffic, seed
+        self.sharded = traffic["workload"] == "sharded"
         self.cache = None
+        self.store_dir: Optional[str] = None
+        self.manifest: Optional[dict] = None
+        self._trace: Optional[tuple] = None  # the store's (block, iter_id)
 
     def hierarchy(self):
         from repro.memsim.config import CacheLevelConfig, HierarchyConfig
@@ -158,15 +191,21 @@ class Traffic:
         from repro.core import WorkloadSpec
 
         per_job = self.traffic["workload"] == "per_job"
-        return WorkloadSpec(
+        spec = WorkloadSpec(
             self.config["kernel"],
             self.config["dataset"],
             hierarchy=self.hierarchy(),
             seed=job_seed(self.seed, index if per_job else 0),
         )
+        if self.sharded:
+            from repro.core.exec.sharded import ShardedSpec
+
+            spec = ShardedSpec(spec, int(self.traffic["shard_accesses"]))
+        return spec
 
     def setup(self) -> None:
-        """Make the graph; with ``per_run``, build the shared workload."""
+        """Make the graph; with ``per_run``, build the shared workload; with
+        ``sharded``, build the shard store."""
         from repro.apps import kernel_traits
         from repro.core import WorkloadCache
         from repro.graphs import make_dataset
@@ -177,71 +216,115 @@ class Traffic:
         if self.traffic["workload"] == "per_run":
             self.cache = WorkloadCache()
             self.cache.get_or_build(self.spec(0))
+        if self.sharded:
+            from repro.core.exec.artifacts import ArtifactCache
+            from repro.core.exec.sharded import ensure_shards
 
-    def prefetchers(self, index: int, streams: dict) -> list:
+            self.store_dir = tempfile.mkdtemp(prefix="bench-shards-")
+            self.cache = WorkloadCache(artifacts=ArtifactCache(self.store_dir))
+            self.manifest = ensure_shards(self.spec(0), self.cache.artifacts)
+
+    def close(self) -> None:
+        """Remove the shard store."""
+        if self.store_dir is not None:
+            shutil.rmtree(self.store_dir, ignore_errors=True)
+            self.store_dir = None
+
+    @staticmethod
+    def chosen(config: dict, traffic: dict, index: int) -> list:
+        """The prefetcher entries job ``index`` scores."""
+        entry = traffic["jobs"][index % len(traffic["jobs"])]
+        chosen = entry["prefetchers"]
+        return config["prefetchers"] if chosen == "config" else chosen
+
+    def prefetchers(self, index: int, streams: dict, spec) -> list:
         from repro.core.registry import get_prefetcher
 
-        entry = self.traffic["jobs"][index % len(self.traffic["jobs"])]
-        chosen = entry["prefetchers"]
-        if chosen == "config":
-            chosen = self.config["prefetchers"]
         out = []
-        for p in chosen:
+        for p in self.chosen(self.config, self.traffic, index):
             generate = get_prefetcher(p["registry"]).instantiate(**p["overrides"])
-            out.append((p["name"], _recording(generate, p["name"], streams)))
+            out.append((p["name"], _recording(generate, (spec, p["name"]), streams)))
         return out
 
     def run(self, index: int) -> Job:
         from repro.core import Experiment
 
         streams: dict = {}
+        spec = self.spec(index)
         result = Experiment(
-            workloads=[self.spec(index)],
-            prefetchers=self.prefetchers(index, streams),
+            workloads=[spec],
+            prefetchers=self.prefetchers(index, streams, spec),
             cache=self.cache,
         ).run(workers=1)
-        accesses = sum(w.num_accesses for w in result.workloads.values())
+        if self.sharded:
+            accesses = int(self.manifest["num_accesses"])
+        else:
+            accesses = sum(w.num_accesses for w in result.workloads.values())
         return Job(index, accesses, result, streams)
 
+    def answers(self, job: Job) -> dict:
+        """A finished job's answers in the shape ``check.compare`` reads."""
+        if self.sharded:
+            return {"workloads": [self._sharded_answers(job)]}
+        out = []
+        for spec, w in job.result.workloads.items():
+            p = w.profile
+            rows = [
+                (c.metrics.row(), job.streams[(spec, c.prefetcher)])
+                for c in job.result.cells
+                if c.spec == spec
+            ]
+            out.append(
+                dict(
+                    seed=spec.seed,
+                    block=w.block,
+                    iter_id=w.iter_id,
+                    eval_from=w.eval_from_pos,
+                    l1_hit=p.l1_hit,
+                    l2_hit=p.l2_hit,
+                    llc_hit=p.llc_hit,
+                    rows=rows,
+                )
+            )
+        return {"workloads": out}
 
-def _recording(generate: Callable, name: str, streams: dict) -> Callable:
-    """``generate``, keeping each stream it issues for the check."""
+    def _sharded_answers(self, job: Job) -> dict:
+        """The trace is the store's shards, as the timed path read them; the
+        rows carry the demand counts in place of per-access masks."""
+        spec = self.spec(job.index)
+        if self._trace is None:
+            store = self.cache.artifacts
+            shards = [
+                store.load_shard(spec, k)
+                for k in range(len(self.manifest["shard_sizes"]))
+            ]
+            self._trace = tuple(
+                np.concatenate([s[key] for s in shards]) for key in ("block", "iter_id")
+            )
+        rows = [
+            (c.metrics.row(), job.streams[(spec, c.prefetcher)])
+            for c in job.result.cells
+        ]
+        return dict(
+            seed=spec.seed,
+            block=self._trace[0],
+            iter_id=self._trace[1],
+            eval_from=self.manifest["eval_from_pos"],
+            rows=rows,
+        )
+
+
+def _recording(generate: Callable, key: tuple, streams: dict) -> Callable:
+    """``generate``, keeping each stream it issues for the check under
+    ``key``: the job's spec and the prefetcher's name.  The workload it is
+    given may be a plain trace or the sharded path's view of one."""
 
     def recorded(workload):
         stream = generate(workload)
-        streams[(workload.spec.seed, name)] = (
-            stream.blocks,
-            stream.pos,
-            stream.metadata_bytes,
-        )
+        streams[key] = (stream.blocks, stream.pos, stream.metadata_bytes)
         return stream
 
     return recorded
-
-
-def answers(job: Job) -> dict:
-    """A finished job's answers in the shape ``check.compare`` reads."""
-    out = []
-    for spec, w in job.result.workloads.items():
-        p = w.profile
-        rows = [
-            (c.metrics.row(), job.streams[(spec.seed, c.prefetcher)])
-            for c in job.result.cells
-            if c.spec == spec
-        ]
-        out.append(
-            dict(
-                seed=spec.seed,
-                block=w.block,
-                iter_id=w.iter_id,
-                eval_from=w.eval_from_pos,
-                l1_hit=p.l1_hit,
-                l2_hit=p.l2_hit,
-                llc_hit=p.llc_hit,
-                rows=rows,
-            )
-        )
-    return {"workloads": out}
 
 
 # ----------------------------------------------------------------- clocks
@@ -379,18 +462,31 @@ def run(
     """One run of a cell; returns the result line as a dict.
 
     ``control`` also reads the correctness control on the same sample: the
-    reference under FIFO replacement put in the program's place.
+    reference under FIFO replacement put in the program's place.  Whatever
+    the traffic stored is removed when the run ends, by failure too.
     """
     cell = load_cell(checkout, workload)
     import jax
 
     devices = chips_or_fail(cell.chips) if require_chip else jax.devices()
+    traffic = Traffic(cell.config, cell.traffic, seed)
+    try:
+        return _measure(cell, traffic, devices, seed, seconds, traced,
+                        require_chip, control, keep_trace, log)
+    finally:
+        traffic.close()
+
+
+def _measure(cell, traffic, devices, seed, seconds, traced, require_chip, control,
+             keep_trace, log) -> dict:
+    """Set-up, the window and the check of :func:`run`."""
+    import jax
+
     compiles = Compiles()
     from repro.core.exec.compile_cache import use_compile_cache
     from repro.core.obs import spans as obs
 
     cache_dir = use_compile_cache()
-    traffic = Traffic(cell.config, cell.traffic, seed)
     traffic.setup()
     warm = traffic.run(0)  # compiles this cell's shapes
     del warm
@@ -435,6 +531,7 @@ def run(
                 "device_peak_bytes": int(peak),
                 "host_peak_rss_bytes": resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
                 * 1024,
+                "host_wchar_bytes": _proc_bytes("io", "wchar:"),
             }
         ),
         flush=True,
@@ -452,6 +549,7 @@ def run(
                 "compiles": compiles.counts,
                 "rss_bytes_at_window": rss_at_window,
                 "compile_cache_bytes": [cache_before, _tree_bytes(cache_dir)],
+                "shard_store_bytes": _tree_bytes(traffic.store_dir or ""),
             }
         ),
         flush=True,
@@ -481,13 +579,25 @@ def run(
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
 
     # The check: the jobs the window kept, drawn from the seed.
-    sample = [answers(j) for j in w.sample]
+    sample = [traffic.answers(j) for j in w.sample]
     w.sample = None
     gc.collect()
-    t_ref = time.perf_counter()
-    reference = check.Reference(cell.config)
-    numbers = check.compare(sample, reference)
-    log(f"[bench] reference {time.perf_counter() - t_ref:.3f} s over {len(sample)} job(s)")
+    with SetGroups(default_workers()) as groups:
+        t_ref = time.perf_counter()
+        reference = check.Reference(cell.config, groups=groups)
+        numbers = check.compare(sample, reference)
+        log(
+            f"[bench] reference {time.perf_counter() - t_ref:.3f} s over "
+            f"{len(sample)} job(s), {groups.workers} process(es)"
+        )
+        if control:
+            t_ref = time.perf_counter()
+            fifo = check.Reference(cell.config, policy="fifo", groups=groups)
+            control_numbers = check.compare(sample, fifo, truth=reference)
+            log(
+                f"[bench] control {time.perf_counter() - t_ref:.3f} s "
+                + json.dumps(control_numbers)
+            )
     result = {
         "correct": failed == 0 and bool(sample) and check.verdict(numbers),
         "attempted": w.jobs + failed,
@@ -498,9 +608,7 @@ def run(
     if breakdown is not None:
         result["breakdown"] = breakdown
     if control:
-        fifo = check.Reference(cell.config, policy="fifo")
-        result["control"] = check.compare(sample, fifo, truth=reference)
-        log("[bench] control " + json.dumps(result["control"]))
+        result["control"] = control_numbers
     result["checks"] = {
         name: {"value": v, "limit": check.LIMITS[name]} for name, v in numbers.items()
     }
@@ -509,12 +617,20 @@ def run(
     return result
 
 
-def _rss_bytes() -> int:
-    with open("/proc/self/status") as f:
-        for line in f:
-            if line.startswith("VmRSS:"):
-                return int(line.split()[1]) * 1024
+def _proc_bytes(name: str, key: str, unit: int = 1) -> int:
+    """A count from ``/proc/self/<name>``, or 0 where it is not there."""
+    try:
+        with open(f"/proc/self/{name}") as f:
+            for line in f:
+                if line.startswith(key):
+                    return int(line.split()[1]) * unit
+    except OSError:
+        pass
     return 0
+
+
+def _rss_bytes() -> int:
+    return _proc_bytes("status", "VmRSS:", 1024)
 
 
 def _tree_bytes(path: str) -> int:

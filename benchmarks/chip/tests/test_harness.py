@@ -43,11 +43,22 @@ def test_adding_a_config_traffic_and_metric_needs_no_edit(tmp_path):
         json.dumps({"workload": "per_job", "jobs": [{"prefetchers": "config"}],
                     "check_jobs": 2})
     )
+    (root / "traffic" / "paper.json").write_text(
+        json.dumps({"workload": "sharded", "shard_accesses": 1 << 22,
+                    "jobs": [{"prefetchers": "config"}], "check_jobs": 1})
+    )
     (root / "metrics" / "jobs_pct.py").write_text("def read(layers):\n    return 42.0\n")
     bench["configs"].append(dict(bench["configs"][0], name="bfs-other",
                                  file="benchmarks/chip/configs/bfs-other.json"))
     bench["workloads"].append({"name": "bfs-other.burst", "config": "bfs-other",
                                "traffic": "burst", "chips": 1, "why": "test"})
+    config["prefetchers"] = [p for p in config["prefetchers"] if p["name"] == "amc"]
+    config["name"] = "bfs-paper"
+    (root / "configs" / "bfs-paper.json").write_text(json.dumps(config))
+    bench["configs"].append(dict(bench["configs"][0], name="bfs-paper",
+                                 file="benchmarks/chip/configs/bfs-paper.json"))
+    bench["workloads"].append({"name": "bfs-paper.paper", "config": "bfs-paper",
+                               "traffic": "paper", "chips": 1, "why": "test"})
     bench["per_layer"].append({"name": "jobs_pct", "unit": "%", "better": "lower",
                                "source": "program_span", "layer": "harness",
                                "moves": "accesses_per_s",
@@ -57,7 +68,32 @@ def test_adding_a_config_traffic_and_metric_needs_no_edit(tmp_path):
     assert cell.config["name"] == "bfs-other" and cell.traffic["check_jobs"] == 2
     assert [m["name"] for m in cell.per_layer] == ["jobs_pct"]
     assert cell.reader("jobs_pct")(None) == 42.0
+    sharded = run_cell.load_cell(tmp_path, "bfs-paper.paper")
+    traffic = run_cell.Traffic(sharded.config, sharded.traffic, seed=2**33 + 1)
+    spec = traffic.spec(3)
+    assert spec.is_sharded and spec.shard_accesses == 1 << 22
+    assert spec == traffic.spec(0) and spec.base.seed == run_cell.job_seed(2**33 + 1, 0)
     assert all(p.read_bytes() == b for p, b in before.items())
+
+
+def test_a_sharded_traffic_naming_a_derived_stream_is_refused_at_load(tmp_path):
+    """The sharded path makes ``nextline2``'s stream itself, so no stream
+    that the check could re-score is ever issued."""
+    root = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(BENCH / "configs", root / "configs")
+    (root / "traffic").mkdir()
+    (root / "traffic" / "paper.json").write_text(json.dumps(
+        {"workload": "sharded", "shard_accesses": 1 << 22, "check_jobs": 1,
+         "jobs": [{"prefetchers": "config"},
+                  {"prefetchers": [{"name": "nextline2", "registry": "nextline2",
+                                    "overrides": {}}]}]}
+    ))
+    bench = json.loads((CHECKOUT / "BENCHMARK.json").read_text())
+    bench["workloads"] = [{"name": "bfs-amazon-scaled.paper", "chips": 1, "why": "t",
+                           "config": "bfs-amazon-scaled", "traffic": "paper"}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(run_cell.BadCell, match="nextline2"):
+        run_cell.load_cell(tmp_path, "bfs-amazon-scaled.paper")
 
 
 class FakeClock:
