@@ -8,7 +8,10 @@ Ligra's sparse edge map reads ``F[v]``, ``T[v]``, ``V[v]`` and then, for
 each out-edge ``e``, ``N[e]`` and the destination's property ``P[dst]``.
 Arrays lie in page-aligned regions (one guard page each) from
 ``0x10000000``, in the order F, T, V, N, P, NI; elements are 1, 8, 8, 4, 8
-and 4 bytes wide; a cache line is 64 bytes.
+and 4 bytes wide; a cache line is 64 bytes.  The application's input is
+F, T, V, N and P; T is the target array whose reads AMC correlates with
+the misses that follow them.  Each run is one epoch, and each BFS level
+one iteration of it.
 """
 from __future__ import annotations
 
@@ -20,14 +23,19 @@ BASE = 0x1000_0000
 PAGE = 4096
 F, T, V, N, P = 0, 1, 2, 3, 4
 ELEM_BYTES = (1, 8, 8, 4, 8, 4)
+INPUT_ARRAYS = (F, T, V, N, P)
 MAX_LEVELS = 200
+
+
+def sizes(n: int, m: int) -> tuple:
+    """Bytes of each array for ``n`` vertices and ``m`` edges."""
+    return (n, 8 * n, 8 * (n + 1), 4 * m, 8 * n, 4 * m)
 
 
 def layout(n: int, m: int) -> np.ndarray:
     """Base address of each array for ``n`` vertices and ``m`` edges."""
-    sizes = (n, 8 * n, 8 * (n + 1), 4 * m, 8 * n, 4 * m)
     bases, addr = [], BASE
-    for size in sizes:
+    for size in sizes(n, m):
         bases.append(addr)
         addr += (-(-size // PAGE) + 1) * PAGE
     return np.array(bases, dtype=np.int64)
@@ -76,7 +84,10 @@ def emit(g: graphs.Graph, frontier: np.ndarray, bases: np.ndarray) -> np.ndarray
 
 def trace(config: dict, seed: int, base_graph: graphs.Graph) -> dict:
     """The workload's line trace: ``blocks``, ``iter_id`` and ``eval_from``
-    (the first access of run 2, where scoring starts)."""
+    (the first access of run 2, where scoring starts); the target reads'
+    positions and vertices (``target_pos``, ``target_vid``) and the target
+    array's ``target_range`` (base, bytes); ``iter_epochs``, each
+    iteration's (epoch, index within it); and ``input_bytes``."""
     churn = config["churn"]
     n = base_graph.num_vertices
     m1, m2 = graphs.churn_masks(
@@ -84,18 +95,29 @@ def trace(config: dict, seed: int, base_graph: graphs.Graph) -> dict:
     )
     g1, g2 = graphs.induced(base_graph, m1), graphs.induced(base_graph, m2)
     root = int(np.argmax(np.where(m1 & m2, g1.degrees, -1)))
-    bases = layout(n, max(g1.num_edges, g2.num_edges))
-    addrs, iters, run_start = [], [], []
+    m = max(g1.num_edges, g2.num_edges)
+    bases, size = layout(n, m), sizes(n, m)
+    addrs, iters, run_start, iter_epochs = [], [], [], []
     it = 0
-    for g, present in ((g1, m1), (g2, m2)):
+    for run, (g, present) in enumerate(((g1, m1), (g2, m2))):
         run_start.append(sum(len(a) for a in addrs))
-        for frontier in levels(g, root, present):
+        for level, frontier in enumerate(levels(g, root, present)):
             a = emit(g, frontier, bases)
             addrs.append(a)
             iters.append(np.full(len(a), it, dtype=np.int32))
+            iter_epochs.append((run, level))
             it += 1
+    addr = np.concatenate(addrs)
+    del addrs
+    target = (addr >= bases[T]) & (addr < bases[T] + size[T])
+    target_pos = np.flatnonzero(target)
     return dict(
-        blocks=np.concatenate(addrs) >> 6,
+        blocks=addr >> 6,
         iter_id=np.concatenate(iters),
         eval_from=run_start[1],
+        target_pos=target_pos,
+        target_vid=(addr[target_pos] - bases[T]) // ELEM_BYTES[T],
+        target_range=(int(bases[T]), size[T]),
+        iter_epochs=iter_epochs,
+        input_bytes=sum(size[k] for k in INPUT_ARRAYS),
     )

@@ -125,6 +125,22 @@ def outcome(
     )
 
 
+def baseline(d: Demand, hierarchy: dict, groups: cache.SetGroups = cache.SERIAL) -> dict:
+    """The baseline run, next-line beside demand, made once per ``d``."""
+    if d.nextline_outcome is None:
+        nl_blocks, nl_pos = nextline(d)
+        d.nextline_outcome = outcome(
+            d,
+            nl_blocks,
+            nl_pos,
+            np.zeros(len(nl_blocks), np.int8),
+            hierarchy,
+            d.policy,
+            groups,
+        )
+    return d.nextline_outcome
+
+
 def _mlp(miss_pos: np.ndarray, window: int, cap: float) -> float:
     if len(miss_pos) < 2:
         return 1.0
@@ -200,17 +216,7 @@ def score(
     under the replacement policy ``d`` was simulated with."""
     policy = d.policy
     nl_blocks, nl_pos = nextline(d)
-    if d.nextline_outcome is None:
-        d.nextline_outcome = outcome(
-            d,
-            nl_blocks,
-            nl_pos,
-            np.zeros(len(nl_blocks), np.int8),
-            hierarchy,
-            policy,
-            groups,
-        )
-    base_o = d.nextline_outcome
+    base_o = baseline(d, hierarchy, groups)
     x_blocks, x_pos, meta_bytes = stream
     x_blocks = np.asarray(x_blocks, dtype=np.int64)
     x_pos = np.asarray(x_pos, dtype=np.int64)

@@ -21,7 +21,9 @@ a profiler trace, and the metrics are the cell's per-layer metrics, each
 read by ``metrics/<name>.py``.
 
 After the window a sample of jobs drawn from the seed is compared with the
-plain reference (``check.py``).  The compared numbers and their limits are
+plain reference (``check.py``), which also regenerates every prefetch
+stream the sample scored; a cell naming a prefetcher that has no plain
+generator is refused at load.  The compared numbers and their limits are
 the last lines of standard error and the last key of the result line,
 which is the last line of standard output.  Without a TPU, or with fewer
 chips than the cell asks for, the run exits non-zero before any job.
@@ -56,6 +58,7 @@ import check  # noqa: E402
 import xplane  # noqa: E402
 from layers import Layers  # noqa: E402
 from reference.cache import SetGroups, default_workers  # noqa: E402
+from reference.prefetchers import GENERATORS, can_generate  # noqa: E402
 
 
 class NoChip(RuntimeError):
@@ -109,12 +112,13 @@ def load_cell(checkout: Path, workload: str) -> Cell:
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
         root=root,
     )
+    entries = [
+        p
+        for index in range(len(cell.traffic["jobs"]))
+        for p in Traffic.chosen(cell.config, cell.traffic, index)
+    ]
     if cell.traffic["workload"] == "sharded":
-        derived = {
-            p["name"]
-            for index in range(len(cell.traffic["jobs"]))
-            for p in Traffic.chosen(cell.config, cell.traffic, index)
-        } & SHARDED_DERIVED
+        derived = {p["name"] for p in entries} & SHARDED_DERIVED
         if derived:
             raise BadCell(
                 f"{workload}: the sharded path derives the stream of "
@@ -122,6 +126,14 @@ def load_cell(checkout: Path, workload: str) -> Cell:
                 "prefetcher, so the check could not re-score it; leave it out "
                 "of a sharded traffic"
             )
+    plainless = sorted({p["name"] for p in entries if not can_generate(p)})
+    if plainless:
+        known = "; ".join(f"{k} with {sorted(g.overrides)}" for k, g in GENERATORS.items())
+        raise BadCell(
+            f"{workload}: {plainless} name a registry entry or an override that "
+            "has no plain generator in reference/prefetchers.py, so the check "
+            f"could not regenerate their streams (there are: {known})"
+        )
     return cell
 
 
@@ -263,17 +275,15 @@ class Traffic:
         return Job(index, accesses, result, streams)
 
     def answers(self, job: Job) -> dict:
-        """A finished job's answers in the shape ``check.compare`` reads."""
+        """A finished job's answers in the shape ``check.compare`` reads:
+        each row with the stream its prefetcher issued and its entry
+        (``registry``, ``overrides``), for the check to regenerate."""
         if self.sharded:
             return {"workloads": [self._sharded_answers(job)]}
         out = []
         for spec, w in job.result.workloads.items():
             p = w.profile
-            rows = [
-                (c.metrics.row(), job.streams[(spec, c.prefetcher)])
-                for c in job.result.cells
-                if c.spec == spec
-            ]
+            rows = [self._row(job, spec, c) for c in job.result.cells if c.spec == spec]
             out.append(
                 dict(
                     seed=spec.seed,
@@ -301,10 +311,7 @@ class Traffic:
             self._trace = tuple(
                 np.concatenate([s[key] for s in shards]) for key in ("block", "iter_id")
             )
-        rows = [
-            (c.metrics.row(), job.streams[(spec, c.prefetcher)])
-            for c in job.result.cells
-        ]
+        rows = [self._row(job, spec, c) for c in job.result.cells]
         return dict(
             seed=spec.seed,
             block=self._trace[0],
@@ -312,6 +319,14 @@ class Traffic:
             eval_from=self.manifest["eval_from_pos"],
             rows=rows,
         )
+
+    def _row(self, job: Job, spec, cell) -> tuple:
+        """A scored row, the stream its prefetcher issued, and its entry."""
+        (entry,) = [
+            p for p in self.chosen(self.config, self.traffic, job.index)
+            if p["name"] == cell.prefetcher
+        ]
+        return cell.metrics.row(), job.streams[(spec, cell.prefetcher)], entry
 
 
 def _recording(generate: Callable, key: tuple, streams: dict) -> Callable:

@@ -2,7 +2,9 @@
 
 A sound run reads every number at 0.  The control (the reference under
 FIFO replacement in the program's place) and each fault planted in the
-timed path below make ``correct`` false.
+timed path below make ``correct`` false.  A fault in a prefetcher's
+generation shows in ``stream_mismatch`` alone: ``row_gap`` reads 0, since
+the reference re-scores the stream that the timed path issued.
 """
 import json
 import shutil
@@ -61,6 +63,7 @@ def test_a_sound_run_is_correct_and_the_control_is_not(checkout, workload):
     assert list(result)[-1] == "checks"
     control = result["control"]
     assert control["hit_mismatch"] > 0 and control["row_gap"] > 0
+    assert control["stream_mismatch"] > 0
     assert any(v > run_cell.check.LIMITS[k] for k, v in control.items())
 
 
@@ -132,10 +135,59 @@ def _alter_a_row(monkeypatch):
     monkeypatch.setattr(experiment, "evaluate", altered)
 
 
+def _vldp_drops_one(monkeypatch):
+    """The program's VLDP drops one prefetch as it filters its candidates."""
+    import repro.core.prefetchers.spatial as spatial
+
+    real = spatial._window_dedupe
+
+    def dropping(blocks, pos, window):
+        keep = real(blocks, pos, window)
+        keep[np.flatnonzero(keep)[len(np.flatnonzero(keep)) // 2]] = False
+        return keep
+
+    monkeypatch.setattr(spatial, "_window_dedupe", dropping)
+
+
+def _amc_one_entry_later(monkeypatch):
+    """AMC's replay issues the first entry it matches one access later."""
+    from repro.core.amc.prefetcher import AMCPrefetcher
+
+    real_generate, real_prefetch = AMCPrefetcher.generate, AMCPrefetcher._prefetch
+    pending = []
+
+    def generate(self, workload, storage=None):
+        pending[:] = [True]
+        return real_generate(self, workload, storage)
+
+    def later(self, it, rec, storage):
+        issued = real_prefetch(self, it, rec, storage)
+        if issued is None or not pending:
+            return issued
+        pending.clear()
+        at = np.searchsorted(rec.trigger_vid, it.target_vid)
+        hit = at < len(rec.trigger_vid)
+        hit[hit] = rec.trigger_vid[at[hit]] == it.target_vid[hit]
+        entry = at[np.argmax(hit)]
+        pos = issued[1].copy()
+        pos[: rec.nmiss[entry]] += 1
+        return issued[0], pos
+
+    monkeypatch.setattr(AMCPrefetcher, "generate", generate)
+    monkeypatch.setattr(AMCPrefetcher, "_prefetch", later)
+
+
+GENERATION_FAULTS = (_vldp_drops_one, _amc_one_entry_later)
+
+
 @pytest.mark.parametrize("fault", [_alter_trace, _flip_a_hit, _state_unchanged,
-                                   _half_the_batch, _alter_a_row])
+                                   _half_the_batch, _alter_a_row, *GENERATION_FAULTS])
 def test_a_fault_in_the_timed_path_makes_the_run_incorrect(checkout, monkeypatch, fault):
     fault(monkeypatch)
     result = _run(checkout)
     assert result["correct"] is False
     assert any(c["value"] > c["limit"] for c in result["checks"].values())
+    if fault in GENERATION_FAULTS:  # what the three other numbers cannot see
+        checks = {k: c["value"] for k, c in result["checks"].items()}
+        assert checks["stream_mismatch"] > 0
+        assert checks["row_gap"] == checks["trace_mismatch"] == checks["hit_mismatch"] == 0

@@ -5,6 +5,7 @@ import os
 import shutil
 import subprocess
 import sys
+import tempfile
 from pathlib import Path
 
 import numpy as np
@@ -29,8 +30,10 @@ def test_every_cell_resolves_its_files_by_name():
             assert callable(cell.reader(m["name"]))
 
 
-def test_adding_a_config_traffic_and_metric_needs_no_edit(tmp_path):
-    """A later change adds files and entries; no file that is there changes."""
+def test_adding_a_config_traffic_and_metric_needs_no_edit(tmp_path, monkeypatch):
+    """A later change adds files and entries; no file that is there changes.
+    A sharded traffic may name VLDP, which the check regenerates; the
+    program has no streaming VLDP yet, so its run fails."""
     root = tmp_path / "benchmarks" / "chip"
     for sub in ("configs", "traffic", "metrics"):
         shutil.copytree(BENCH / sub, root / sub)
@@ -52,8 +55,9 @@ def test_adding_a_config_traffic_and_metric_needs_no_edit(tmp_path):
                                  file="benchmarks/chip/configs/bfs-other.json"))
     bench["workloads"].append({"name": "bfs-other.burst", "config": "bfs-other",
                                "traffic": "burst", "chips": 1, "why": "test"})
-    config["prefetchers"] = [p for p in config["prefetchers"] if p["name"] == "amc"]
-    config["name"] = "bfs-paper"
+    config.update(name="bfs-paper", dataset="tinyroad",
+                  graph={"kind": "road", "n": 20000, "shortcut_frac": 0.0, "seed": 18})
+    assert {p["registry"] for p in config["prefetchers"]} == {"amc", "vldp"}
     (root / "configs" / "bfs-paper.json").write_text(json.dumps(config))
     bench["configs"].append(dict(bench["configs"][0], name="bfs-paper",
                                  file="benchmarks/chip/configs/bfs-paper.json"))
@@ -74,6 +78,37 @@ def test_adding_a_config_traffic_and_metric_needs_no_edit(tmp_path):
     assert spec.is_sharded and spec.shard_accesses == 1 << 22
     assert spec == traffic.spec(0) and spec.base.seed == run_cell.job_seed(2**33 + 1, 0)
     assert all(p.read_bytes() == b for p, b in before.items())
+    from repro.core.exec.sharded import ShardedScoringError
+
+    scratch = tmp_path / "tmp"
+    scratch.mkdir()
+    monkeypatch.setattr(tempfile, "tempdir", str(scratch))
+    with pytest.raises(ShardedScoringError, match="vldp"):
+        run_cell.run(tmp_path, "bfs-paper.paper", 2**33 + 1, 0.01, traced=False,
+                     require_chip=False, log=lambda s: None)
+    assert list(scratch.iterdir()) == []
+
+
+@pytest.mark.parametrize("entry", [
+    {"name": "bingo", "registry": "bingo", "overrides": {}},
+    {"name": "amc.small", "registry": "amc", "overrides": {"storage_fraction": 0.2}},
+], ids=["bingo", "amc-storage_fraction"])
+def test_a_traffic_naming_a_prefetcher_with_no_plain_generator_is_refused_at_load(
+        tmp_path, entry):
+    """The check could not regenerate that prefetcher's stream."""
+    root = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(BENCH / "configs", root / "configs")
+    (root / "traffic").mkdir()
+    (root / "traffic" / "grid.json").write_text(json.dumps(
+        {"workload": "per_run", "check_jobs": 1,
+         "jobs": [{"prefetchers": "config"}, {"prefetchers": [entry]}]}
+    ))
+    bench = json.loads((CHECKOUT / "BENCHMARK.json").read_text())
+    bench["workloads"] = [{"name": "bfs-amazon-scaled.grid", "chips": 1, "why": "t",
+                           "config": "bfs-amazon-scaled", "traffic": "grid"}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(run_cell.BadCell, match=entry["name"]):
+        run_cell.load_cell(tmp_path, "bfs-amazon-scaled.grid")
 
 
 def test_a_sharded_traffic_naming_a_derived_stream_is_refused_at_load(tmp_path):

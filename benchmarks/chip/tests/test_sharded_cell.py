@@ -89,6 +89,7 @@ def test_a_sound_run_is_correct_the_control_is_not_and_no_file_stays(
     assert all(c["value"] == 0 for c in result["checks"].values())
     control = result["control"]
     assert control["hit_mismatch"] > 0 and control["row_gap"] > 0
+    assert control["stream_mismatch"] > 0
     # The store held the shards of one job of many shards, then went.
     (names,) = stored
     assert sum(n.endswith(".npz") for n in names) > 4
@@ -136,10 +137,30 @@ def _demand_count_off_by_one(monkeypatch):
     monkeypatch.setattr(sharded, "_metrics", off)
 
 
+def _alter_a_spilled_miss(monkeypatch):
+    """One block of the miss spill that AMC's view of the shards yields."""
+    import repro.core.exec.sharded as sharded
+
+    real = sharded._ShardedWorkloadView.amc_iteration_views
+
+    def altered(self):
+        done = False
+        for view, epoch in real(self):
+            if not done and len(view.miss_blocks):
+                blocks = view.miss_blocks.copy()
+                blocks[len(blocks) // 2] += 1
+                view = dataclasses.replace(view, miss_blocks=blocks)
+                done = True
+            yield view, epoch
+
+    monkeypatch.setattr(sharded._ShardedWorkloadView, "amc_iteration_views", altered)
+
+
 @pytest.mark.parametrize("fault,number", [
     (_alter_an_access, "trace_mismatch"),
     (_perturb_a_row, "row_gap"),
     (_demand_count_off_by_one, "hit_mismatch"),
+    (_alter_a_spilled_miss, "stream_mismatch"),
 ])
 def test_a_fault_in_the_sharded_path_makes_the_run_incorrect(
         checkout, tmpdir_only, monkeypatch, fault, number):
@@ -147,6 +168,8 @@ def test_a_fault_in_the_sharded_path_makes_the_run_incorrect(
     result = _run(checkout)
     assert result["correct"] is False
     assert result["checks"][number]["value"] > result["checks"][number]["limit"]
+    if number == "stream_mismatch":  # generation alone: the rest read 0
+        assert all(c["value"] == 0 for k, c in result["checks"].items() if k != number)
     assert list(tmpdir_only.iterdir()) == []
 
 
@@ -155,6 +178,7 @@ def test_a_demand_count_is_compared_in_place_of_masks():
     import check
 
     row = {f: 1.0 for f in check.ROW_FIELDS}
+    stream = (np.array([7, 9]), np.array([2, 2]), 64)
 
     class Ref:
         def simulate(self, seed):
@@ -164,9 +188,14 @@ def test_a_demand_count_is_compared_in_place_of_masks():
         def row(self, d, stream, eval_from):
             return dict(row, dram_demand=5.0, baseline_l2_misses=7.0)
 
+        def stream(self, seed, entry):
+            return stream
+
     job = {"workloads": [dict(seed=1, block=np.zeros(3, np.int64),
                               iter_id=np.zeros(3, np.int32), eval_from=1,
                               rows=[(dict(row, dram_demand=4.0, baseline_l2_misses=9.0),
-                                     None)])]}
+                                     stream, {"name": "amc", "registry": "amc",
+                                              "overrides": {}})])]}
     numbers = check.compare([job], Ref())
     assert numbers["hit_mismatch"] == 1 + 2 and numbers["trace_mismatch"] == 0
+    assert numbers["stream_mismatch"] == 0
